@@ -13,6 +13,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.caching import use_persistent_compile_cache
+
 from . import cache_micro, kernels_bench, plan_bench, precompute_bench, \
     table2_reproduction
 
@@ -27,6 +29,7 @@ SUITES = {
 
 
 def main(argv=None) -> None:
+    use_persistent_compile_cache()
     args = argv if argv is not None else sys.argv[1:]
     names = args or list(SUITES)
     for name in names:

@@ -60,7 +60,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.caching import warm_scenario
+from repro.caching import use_persistent_compile_cache, warm_scenario
 from repro.serve import (PipelineService, ServeConfig, build_scenario,
                          build_service, run_closed_loop)
 
@@ -96,32 +96,27 @@ def run_epoch(name: str, scenario, cache_dir: str, *, requests: int,
     return row
 
 
-def _fleet_bit_identity(svc, scenario) -> bool:
-    """Serve every topic through the fleet and compare per-qid frames
-    against the offline pipeline run, bit for bit."""
-    offline = scenario.pipeline(scenario.topics)
-    qids = [str(q) for q in scenario.topics["qid"].tolist()]
-    queries = scenario.topics["query"].tolist()
-    futs = [(qid, svc.submit(qid, query, **scenario.request_extra.get(qid, {})))
-            for qid, query in zip(qids, queries)]
-    for qid, fut in futs:
-        served = fut.result(120)
-        ref = offline.take(np.nonzero(offline["qid"] == qid)[0])
-        if not served.equals(ref):
-            return False
-    return True
-
-
 def run_fleet_epoch(name: str, cfg: ServeConfig, *, requests: int,
                     clients: int, seed: int,
                     check_identity: bool = False) -> Dict:
-    svc = build_service(cfg)
+    # with workers > 1 the parent generates traffic from the corpus
+    # alone and builds the pipeline only after the fleet has drained:
+    # the devices belong to the worker processes while they run
+    scenario = cfg.build_scenario() if cfg.workers == 1 \
+        else cfg.build_traffic()
+    served = {}
+    svc = build_service(cfg, scenario=scenario if cfg.workers == 1 else None)
     try:
-        scenario = cfg.build_scenario()
         loop = run_closed_loop(svc, scenario, n_requests=requests,
                                n_clients=clients, seed=seed)
-        identical = (_fleet_bit_identity(svc, scenario)
-                     if check_identity else None)
+        if check_identity:
+            # serve every topic once more, kept for the offline check
+            qids = [str(q) for q in scenario.topics["qid"].tolist()]
+            futs = [(qid, svc.submit(qid, query,
+                                     **scenario.request_extra.get(qid, {})))
+                    for qid, query in zip(qids,
+                                          scenario.topics["query"].tolist())]
+            served = {qid: fut.result(120) for qid, fut in futs}
         if cfg.workers > 1:
             report = svc.drain()
             online = report["online"]
@@ -132,6 +127,16 @@ def run_fleet_epoch(name: str, cfg: ServeConfig, *, requests: int,
         summary = svc.stats.summary()
     finally:
         svc.close()
+    identical = None
+    if check_identity:
+        # every served per-qid frame equals the offline pipeline run,
+        # bit for bit
+        full = scenario if scenario.pipeline is not None \
+            else cfg.build_scenario()
+        offline = full.pipeline(full.topics)
+        identical = all(
+            frame.equals(offline.take(np.nonzero(offline["qid"] == qid)[0]))
+            for qid, frame in served.items())
     row = {"name": name, "workers": cfg.workers, **loop,
            "p50_ms": round(summary["p50_ms"], 4),
            "p99_ms": round(summary["p99_ms"], 4),
@@ -183,16 +188,51 @@ def main(argv: Optional[List[str]] = None):
     requests = args.requests or (120 if args.quick else 600)
     scale = args.scale or (0.02 if args.quick else 0.05)
 
-    scenario = build_scenario("bm25-mono", scale=scale, cutoff=args.cutoff,
-                              num_results=100, seed=args.seed)
+    use_persistent_compile_cache()
     tmp = None
     cache_dir = args.cache_dir
     if cache_dir is None:
         tmp = tempfile.TemporaryDirectory(prefix="serve-bench-")
         cache_dir = tmp.name
+    rows = []
+
+    fleet_scaling = None
+    if args.fleet:
+        # fleet epochs first, while this process is still off JAX (a
+        # device belongs to one process): warmed shared cache (mmap read-mostly tier) +
+        # uncacheable simulated device latency; max_batch=1 /
+        # exec_workers=1 model one synchronous replica per process, so
+        # the only parallelism measured is the fleet's
+        fleet_dir = os.path.join(cache_dir, "fleet")
+        base = ServeConfig(pipeline="bm25-sim", scale=scale,
+                           cutoff=args.cutoff, num_results=100,
+                           seed=args.seed, cache_dir=fleet_dir,
+                           backend="mmap:sqlite", max_batch=1,
+                           max_wait_ms=0.0, exec_workers=1)
+        fleet_offline = warm_scenario(None, fleet_dir, config=base)
+        print(f"[fleet_offline] precomputed "
+              f"{fleet_offline['queries_warmed']} query(s) into the "
+              f"shared {base.backend} store")
+        fleet_requests = args.requests or (160 if args.quick else 400)
+        w1 = run_fleet_epoch("fleet_w1", base,
+                             requests=fleet_requests,
+                             clients=args.fleet_clients, seed=args.seed)
+        wn = run_fleet_epoch(f"fleet_w{args.fleet_workers}",
+                             dataclasses.replace(
+                                 base, workers=args.fleet_workers),
+                             requests=fleet_requests,
+                             clients=args.fleet_clients, seed=args.seed,
+                             check_identity=True)
+        rows.extend([w1, wn])
+        fleet_scaling = round(
+            wn["throughput_rps"] / max(w1["throughput_rps"], 1e-9), 2)
+        print(f"fleet scaling 1->{args.fleet_workers}: {fleet_scaling}x "
+              f"(bit_identical={wn['bit_identical']})")
+
+    scenario = build_scenario("bm25-mono", scale=scale, cutoff=args.cutoff,
+                              num_results=100, seed=args.seed)
 
     prefetch = not args.no_prefetch
-    rows = []
     for epoch in ("serve_cold", "serve_warm"):
         rows.append(run_epoch(epoch, scenario, cache_dir,
                               requests=requests, clients=args.clients,
@@ -200,7 +240,7 @@ def main(argv: Optional[List[str]] = None):
                               max_wait_ms=args.max_wait_ms,
                               workers=args.workers, seed=args.seed,
                               prefetch=prefetch))
-    cold, warm = rows
+    cold, warm = rows[-2:]
     print(f"warm/cold p50: {warm['p50_ms']}/{cold['p50_ms']}ms "
           f"({cold['p50_ms'] / max(warm['p50_ms'], 1e-9):.1f}x)")
 
@@ -237,38 +277,6 @@ def main(argv: Optional[List[str]] = None):
     print(f"warmed/warm p50: {warmed['p50_ms']}/{warm['p50_ms']}ms "
           f"({warmed['p50_ms'] / max(warm['p50_ms'], 1e-9):.2f}x, "
           f"misses={warmed['cache_misses']})")
-
-    fleet_scaling = None
-    if args.fleet:
-        # fleet epochs: warmed shared cache (mmap read-mostly tier) +
-        # uncacheable simulated device latency; max_batch=1 /
-        # exec_workers=1 model one synchronous replica per process, so
-        # the only parallelism measured is the fleet's
-        fleet_dir = os.path.join(cache_dir, "fleet")
-        base = ServeConfig(pipeline="bm25-sim", scale=scale,
-                           cutoff=args.cutoff, num_results=100,
-                           seed=args.seed, cache_dir=fleet_dir,
-                           backend="mmap:sqlite", max_batch=1,
-                           max_wait_ms=0.0, exec_workers=1)
-        fleet_offline = warm_scenario(None, fleet_dir, config=base)
-        print(f"[fleet_offline] precomputed "
-              f"{fleet_offline['queries_warmed']} query(s) into the "
-              f"shared {base.backend} store")
-        fleet_requests = args.requests or (160 if args.quick else 400)
-        w1 = run_fleet_epoch("fleet_w1", base,
-                             requests=fleet_requests,
-                             clients=args.fleet_clients, seed=args.seed)
-        wn = run_fleet_epoch(f"fleet_w{args.fleet_workers}",
-                             dataclasses.replace(
-                                 base, workers=args.fleet_workers),
-                             requests=fleet_requests,
-                             clients=args.fleet_clients, seed=args.seed,
-                             check_identity=True)
-        rows.extend([w1, wn])
-        fleet_scaling = round(
-            wn["throughput_rps"] / max(w1["throughput_rps"], 1e-9), 2)
-        print(f"fleet scaling 1->{args.fleet_workers}: {fleet_scaling}x "
-              f"(bit_identical={wn['bit_identical']})")
 
     if args.json:
         payload = {"rows": rows, "requests": requests, "scale": scale,

@@ -27,7 +27,8 @@ from .lazy import Lazy
 from .artifact import Artifact, to_hub, from_hub, hub_dir, \
     install_artifact_methods
 from .bucketing import BucketedRunner, bucket_size, pad_batch
-from .compile_cache import CompileCache, default_compile_cache
+from .compile_cache import (CompileCache, default_compile_cache,
+                            use_persistent_compile_cache)
 from .auto import (auto_cache, auto_cache_or_none, derive_fingerprint,
                    typecheck_pipeline, UncacheableError)
 
@@ -54,7 +55,7 @@ __all__ = [
     "KeyValueCache", "ScorerCache", "DenseScorerCache", "RetrieverCache",
     "IndexerCache", "Lazy", "Artifact", "to_hub", "from_hub", "hub_dir",
     "BucketedRunner", "bucket_size", "pad_batch",
-    "CompileCache", "default_compile_cache",
+    "CompileCache", "default_compile_cache", "use_persistent_compile_cache",
     "auto_cache", "auto_cache_or_none", "derive_fingerprint",
     "typecheck_pipeline", "UncacheableError",
 ]

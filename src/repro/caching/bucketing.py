@@ -16,7 +16,17 @@ from typing import Any, Callable, Dict, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["bucket_size", "pad_batch", "BucketedRunner"]
+__all__ = ["bucket_size", "pad_batch", "BucketedRunner",
+           "DEVICE_BATCH_FLOOR"]
+
+#: fewest rows a device stage runs at once.  On TPU, XLA compiles small
+#: batches to other code paths than large ones, and their f32 results
+#: differ in the low bits (measured on a v5e: mono-scorer rows scored
+#: in batches of 8-32 against 64-1024, dense top-k over 1 query against
+#: 43), so a row's result would depend on the batch it arrived in.  At
+#: this floor and above it did not, which keeps a served frame equal to
+#: the offline run's.
+DEVICE_BATCH_FLOOR = 64
 
 
 def bucket_size(n: int, *, floor: int = 8, ceiling: int = 1 << 20) -> int:

@@ -5,27 +5,47 @@ model compute but by XLA *compilation* (minutes for large models).  Two
 experiment pipelines sharing the same scorer at the same shapes should
 pay that cost once — the exact analogue, one level down, of the paper's
 prefix precomputation.  ``CompileCache`` memoizes lowered+compiled
-executables keyed by (function identity, abstract input signature, mesh
-fingerprint).
+executables in-process, keyed by (function identity, abstract input
+signature, jit options).
 
-An optional on-disk layer persists serialized executables across
-processes via ``jax.experimental.serialize_executable`` where the
-backend supports it (best-effort: deserialization failures fall back to
-recompilation — correctness never depends on the disk layer).
+Across processes the compiled programs persist through JAX's own
+persistent compilation cache, which :func:`use_persistent_compile_cache`
+places: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it, and
+otherwise the cache lives at one fixed path inside the checkout
+(``<repo>/.jax_cache``).  The path is part of the cache's key, so it is
+never derived from a temp name, a pid or the time.
 """
 from __future__ import annotations
 
-import hashlib
 import os
-import pickle
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
-import numpy as np
 
-__all__ = ["CompileCache", "signature_of_args"]
+__all__ = ["CompileCache", "signature_of_args", "default_compile_cache",
+           "use_persistent_compile_cache", "DEFAULT_JAX_CACHE_DIR"]
+
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: ``.jax_cache`` at the root of the checkout (gitignored)
+DEFAULT_JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_persistent_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; call before the first
+    compile.  Returns the directory in use.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already read it into
+    ``jax_compilation_cache_dir`` and nothing is changed; otherwise the
+    cache goes to :data:`DEFAULT_JAX_CACHE_DIR`.  Touches no backend.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_JAX_CACHE_DIR)
+    return DEFAULT_JAX_CACHE_DIR
 
 
 def _abstractify(x: Any):
@@ -43,64 +63,33 @@ def signature_of_args(args, kwargs) -> Tuple:
 class CompileCacheStats:
     compile_hits: int = 0
     compile_misses: int = 0
-    disk_hits: int = 0
     compile_time_s: float = 0.0
 
     def __str__(self):
         return (f"compiles={self.compile_misses} reuses={self.compile_hits} "
-                f"disk_hits={self.disk_hits} "
                 f"compile_time={self.compile_time_s:.2f}s")
 
 
 class CompileCache:
-    """Process-wide executable cache with optional disk persistence."""
+    """Process-wide memo of compiled executables."""
 
-    def __init__(self, path: Optional[str] = None):
-        self.path = path
-        if path:
-            os.makedirs(path, exist_ok=True)
+    def __init__(self):
         self._mem: Dict[Tuple, Any] = {}
         self.stats = CompileCacheStats()
-
-    def _mesh_fingerprint(self) -> str:
-        # Capture the ambient mesh if any (set via `with mesh:`).
-        try:
-            from jax.interpreters import pxla
-            env = pxla.thread_resources.env
-            m = env.physical_mesh
-            if m.empty:
-                return "nomesh"
-            return f"{tuple(m.shape.items())}"
-        except Exception:
-            return "nomesh"
-
-    def _disk_key(self, key: Tuple) -> str:
-        return hashlib.sha256(repr(key).encode()).hexdigest()[:24]
 
     def get_compiled(self, name: str, fn: Callable, *args,
                      jit_kwargs: Optional[dict] = None, **kwargs):
         """Return a compiled executable for fn at these (abstract) args."""
         jit_kwargs = jit_kwargs or {}
         key = (name, signature_of_args(args, kwargs),
-               self._mesh_fingerprint(),
                tuple(sorted((k, repr(v)) for k, v in jit_kwargs.items())))
         hit = self._mem.get(key)
         if hit is not None:
             self.stats.compile_hits += 1
             return hit
-        jitted = jax.jit(fn, **jit_kwargs)
         t0 = time.perf_counter()
-        compiled = None
-        if self.path:
-            compiled = self._try_load_disk(key, jitted, args, kwargs)
-            if compiled is not None:
-                self.stats.disk_hits += 1
-        if compiled is None:
-            lowered = jitted.lower(*args, **kwargs)
-            compiled = lowered.compile()
-            self.stats.compile_misses += 1
-            if self.path:
-                self._try_save_disk(key, compiled)
+        compiled = jax.jit(fn, **jit_kwargs).lower(*args, **kwargs).compile()
+        self.stats.compile_misses += 1
         self.stats.compile_time_s += time.perf_counter() - t0
         self._mem[key] = compiled
         return compiled
@@ -110,30 +99,6 @@ class CompileCache:
         compiled = self.get_compiled(name, fn, *args,
                                      jit_kwargs=jit_kwargs, **kwargs)
         return compiled(*args, **kwargs)
-
-    # -- disk layer (best-effort) ---------------------------------------------
-    def _try_save_disk(self, key: Tuple, compiled) -> None:
-        try:
-            from jax.experimental import serialize_executable as se
-            payload = se.serialize(compiled)
-            with open(os.path.join(self.path, self._disk_key(key)), "wb") as f:
-                pickle.dump(payload, f)
-        except Exception:
-            pass
-
-    def _try_load_disk(self, key: Tuple, jitted, args, kwargs):
-        try:
-            from jax.experimental import serialize_executable as se
-            p = os.path.join(self.path, self._disk_key(key))
-            if not os.path.exists(p):
-                return None
-            with open(p, "rb") as f:
-                payload = pickle.load(f)
-            return se.deserialize_and_load(payload[0], payload[1], payload[2]) \
-                if isinstance(payload, tuple) and len(payload) == 3 \
-                else se.deserialize_and_load(*payload)
-        except Exception:
-            return None
 
 
 #: module-level default instance (shared across pipeline stages)

@@ -10,9 +10,9 @@ corpus or code change.  This module closes that gap:
   fingerprint: class identity (module + qualname + a hash of the class
   source when obtainable) plus its structural ``signature()`` plus any
   declared ``fingerprint_extras()`` (corpus versions, checkpoint ids),
-  hashed with the dual-lane FNV-1a digest of the ``cachekey_hash``
-  kernel (``kernels/cachekey_hash``) when JAX is importable, and with a
-  bit-identical pure-Python implementation otherwise.  The execution
+  hashed on the host with the dual-lane FNV-1a digest (bit-identical
+  to the ``kernels/cachekey_hash`` kernel's), so fingerprinting never
+  touches JAX or a device.  The execution
   planner extends this to *node* fingerprints by folding in the
   fingerprints of all upstream nodes, so invalidation propagates
   downstream exactly as results do.
@@ -132,16 +132,15 @@ def _encode(o: Any, out: bytearray) -> None:
 
 
 # Constants mirror kernels/cachekey_hash/ref.py — the host digest below
-# is the kernel's bit-identical reference ("shared cache entries").
+# is bit-identical to that kernel's, so directories keyed by either
+# digest stay valid.
 _FNV_OFFSET = 0x811C9DC5
 _FNV_PRIME = 0x01000193
 _LANE2_OFFSET = 0x31415927
 
-#: digests pad token buffers to multiples of this many uint32 words so
-#: the jitted kernel compiles O(1) distinct shapes, not one per payload
+#: digests pad token buffers to multiples of this many uint32 words
+#: (the kernel's word bucket; part of the digest's definition)
 _WORD_BUCKET = 64
-
-_DIGEST_IMPL = None
 
 
 def _host_digest(words: np.ndarray) -> bytes:
@@ -154,44 +153,6 @@ def _host_digest(words: np.ndarray) -> bytes:
     return h0.to_bytes(4, "little") + h1.to_bytes(4, "little")
 
 
-def _kernel_digest_factory():
-    from ..kernels.cachekey_hash.ops import cachekey_hash_op
-
-    def impl(words: np.ndarray) -> bytes:
-        tokens = np.ascontiguousarray(words, dtype=np.uint32) \
-            .view(np.int32).reshape(1, -1)
-        out = np.asarray(cachekey_hash_op(tokens))
-        return (int(out[0, 0]) & 0xFFFFFFFF).to_bytes(4, "little") + \
-               (int(out[0, 1]) & 0xFFFFFFFF).to_bytes(4, "little")
-    return impl
-
-
-def _digest_impl():
-    """Resolve the digest implementation once per process.
-
-    ``REPRO_PROVENANCE_HASH`` selects: ``auto`` (default — the
-    ``cachekey_hash`` kernel when JAX imports, else the pure-Python
-    fallback), ``kernel`` (require the kernel) or ``host`` (skip JAX
-    entirely; useful for lightweight CLI invocations).  Both paths
-    produce identical digests (asserted in tests/test_provenance.py).
-    """
-    global _DIGEST_IMPL
-    if _DIGEST_IMPL is None:
-        mode = os.environ.get("REPRO_PROVENANCE_HASH", "auto")
-        if mode == "host":
-            _DIGEST_IMPL = _host_digest
-        else:
-            try:
-                impl = _kernel_digest_factory()
-                impl(np.zeros(_WORD_BUCKET, dtype=np.uint32))  # smoke
-                _DIGEST_IMPL = impl
-            except Exception:
-                if mode == "kernel":
-                    raise
-                _DIGEST_IMPL = _host_digest
-    return _DIGEST_IMPL
-
-
 def digest_bytes(data: bytes) -> str:
     """16-hex-char dual-lane FNV digest of ``data`` (length-prefixed,
     zero-padded to the kernel's word bucket)."""
@@ -202,7 +163,7 @@ def digest_bytes(data: bytes) -> str:
     if target > len(words):
         words = np.concatenate(
             [words, np.zeros(target - len(words), dtype="<u4")])
-    return _digest_impl()(words).hex()
+    return _host_digest(words).hex()
 
 
 # ---------------------------------------------------------------------------

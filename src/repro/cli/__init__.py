@@ -34,5 +34,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from ..caching import use_persistent_compile_cache
     args = build_parser().parse_args(argv)
+    use_persistent_compile_cache()
     return int(args.func(args) or 0)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..caching.bucketing import DEVICE_BATCH_FLOOR, bucket_size, pad_batch
 from ..caching.compile_cache import default_compile_cache
 from ..core.frame import ColFrame
 from ..core.pipeline import Transformer
@@ -111,12 +112,9 @@ class DenseEncoder:
         outs = []
         for lo in range(0, len(texts), batch):
             chunk = texts[lo:lo + batch]
-            toks = self.tokenizer.encode_batch(chunk, self.cfg.max_len)
-            pad = (-len(chunk)) % 8
-            if pad:
-                toks = np.concatenate([toks, np.zeros((pad,
-                                                       self.cfg.max_len),
-                                                      np.int32)])
+            toks = pad_batch(
+                self.tokenizer.encode_batch(chunk, self.cfg.max_len),
+                bucket_size(len(chunk), floor=DEVICE_BATCH_FLOOR))
             emb = default_compile_cache.call(
                 f"dense_encode:{self.cfg.name}", self._embed_fn,
                 jnp.asarray(toks))
@@ -235,16 +233,20 @@ class DenseIndex:
         order (score desc, doc index asc) — deterministic ties, so
         top-k is a prefix of top-n and cutoff fusion is sound."""
         k = int(min(k, len(self.docnos)))
+        n_q = len(q_emb)
         parts_v, parts_i = [], []
-        qj = jnp.asarray(q_emb, jnp.float32)
+        qj = np.zeros((bucket_size(n_q, floor=DEVICE_BATCH_FLOOR),
+                       q_emb.shape[1]), np.float32)
+        qj[:n_q] = q_emb
+        qj = jnp.asarray(qj)
         for lo, chunk in self.device_chunks():
             kk = min(k, int(chunk.shape[0]))
             if backend == "pallas":
                 v, i = dense_topk_op(qj, chunk, k=kk)
             else:
                 v, i = _xla_chunk_topk(qj, chunk, kk)
-            parts_v.append(np.asarray(v))
-            parts_i.append(np.asarray(i) + lo)
+            parts_v.append(np.asarray(v)[:n_q])
+            parts_i.append(np.asarray(i)[:n_q] + lo)
         vals = np.concatenate(parts_v, axis=1)
         idxs = np.concatenate(parts_i, axis=1)
         out_v = np.empty((len(q_emb), k), np.float32)

@@ -8,10 +8,12 @@ hostile to the VPU).  The TPU-native formulation processes a dense
 * grid ``(docs/bd, terms/bt)`` with terms innermost: the per-doc score
   accumulator block stays in VMEM across term tiles;
 * each step: load ``tf [bt, bd]``, apply the BM25 saturation
-  elementwise on the VPU, then a ``[1,bt]×[bt,bd]`` idf contraction on
-  the MXU; accumulate into ``scores [1, bd]``;
-* tiles are (8×128)-aligned; zero tf contributes exactly 0, so the
-  sparse→dense padding does not change scores.
+  elementwise on the VPU, weight each term row by its idf and reduce
+  over the term (sublane) axis; accumulate into ``scores [1, bd]``;
+* tiles are (8×128)-aligned — idf travels as a ``[T, 1]`` column so its
+  ``(bt, 1)`` block spans the full lane dimension, which Mosaic
+  requires of a block narrower than 128 lanes; zero tf contributes
+  exactly 0, so the sparse→dense padding does not change scores.
 
 The postings→tile densification is done host-side per query-term batch
 (the tile is the *unit of transfer*, matching how one would stream
@@ -39,13 +41,11 @@ def _kernel(tf_ref, idf_ref, dl_ref, o_ref, *, k1: float, b: float,
 
     tf = tf_ref[...].astype(jnp.float32)          # [bt, bd]
     dl = dl_ref[...].astype(jnp.float32)          # [1, bd]
-    idf = idf_ref[...].astype(jnp.float32)        # [1, bt]
+    idf = idf_ref[...].astype(jnp.float32)        # [bt, 1]
     dl_norm = k1 * (1.0 - b + b * dl / avg_dl)    # [1, bd]
     sat = tf * (k1 + 1.0) / (tf + dl_norm)        # [bt, bd]
     sat = jnp.where(tf > 0, sat, 0.0)
-    o_ref[...] += jax.lax.dot_general(
-        idf, sat, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # [1, bd]
+    o_ref[...] += jnp.sum(idf * sat, axis=0, keepdims=True)   # [1, bd]
 
 
 def bm25_block(tf: jnp.ndarray, idf: jnp.ndarray, doc_len: jnp.ndarray, *,
@@ -55,7 +55,7 @@ def bm25_block(tf: jnp.ndarray, idf: jnp.ndarray, doc_len: jnp.ndarray, *,
     """tf [T,D]; idf [T]; doc_len [D] -> scores [D]."""
     T, D = tf.shape
     assert T % block_t == 0 and D % block_d == 0
-    idf2 = idf[None, :]                            # [1, T]
+    idf2 = idf[:, None]                            # [T, 1]
     dl2 = doc_len[None, :]                         # [1, D]
     out = pl.pallas_call(
         functools.partial(_kernel, k1=k1, b=b, avg_dl=avg_dl,
@@ -63,7 +63,7 @@ def bm25_block(tf: jnp.ndarray, idf: jnp.ndarray, doc_len: jnp.ndarray, *,
         grid=(D // block_d, T // block_t),
         in_specs=[
             pl.BlockSpec((block_t, block_d), lambda di, ti: (ti, di)),
-            pl.BlockSpec((1, block_t), lambda di, ti: (0, ti)),
+            pl.BlockSpec((block_t, 1), lambda di, ti: (ti, 0)),
             pl.BlockSpec((1, block_d), lambda di, ti: (0, di)),
         ],
         out_specs=pl.BlockSpec((1, block_d), lambda di, ti: (0, di)),

@@ -51,7 +51,10 @@ def _kernel(q_ref, c_ref, v_ref, i_ref, vals_scr, idxs_scr, *,
 
     q = q_ref[...].astype(jnp.float32)               # [bq, d]
     c = c_ref[...].astype(jnp.float32)               # [bd, d]
+    # full f32 contraction: ranks must match the f32 reference, which a
+    # single bf16 MXU pass would not guarantee on near-ties
     s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # [bq, bd]
     bq = s.shape[0]
     dpos = di * bd + jax.lax.broadcasted_iota(jnp.int32, (bq, bd), 1)

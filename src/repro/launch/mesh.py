@@ -16,24 +16,13 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases do not
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_mesh", "mesh_info"]
 
 
 def _make(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -189,8 +189,6 @@ def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,
                      compile_s: float = 0.0,
                      notes: str = "") -> RooflineReport:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<0.5 returns [dict] per device
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

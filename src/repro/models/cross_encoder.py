@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..caching.bucketing import BucketedRunner
+from ..caching.bucketing import DEVICE_BATCH_FLOOR, BucketedRunner
 from ..caching.compile_cache import default_compile_cache
 from ..core.frame import ColFrame
 from ..core.pipeline import Transformer, add_ranks
@@ -132,7 +132,8 @@ class _EncoderBase(Transformer):
                 f"{type(self).__name__}:{cfg.name}",
                 lambda t: encoder_score(self.params, t, self.cfg), tokens)
 
-        self._runner = BucketedRunner(_score, floor=8, max_bucket=1024)
+        self._runner = BucketedRunner(_score, floor=DEVICE_BATCH_FLOOR,
+                                      max_bucket=1024)
 
     def _score_pairs(self, queries, texts) -> np.ndarray:
         toks = np.stack([

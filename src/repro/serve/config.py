@@ -121,6 +121,12 @@ class ServeConfig:
                               cutoff=self.cutoff,
                               num_results=self.num_results, seed=self.seed)
 
+    def build_traffic(self):
+        """This config's request pool without its pipeline (numpy only:
+        what a fleet's parent needs, leaving the device to workers)."""
+        from .registry import build_traffic
+        return build_traffic(self.pipeline, scale=self.scale, seed=self.seed)
+
     def service_kwargs(self) -> Dict[str, Any]:
         """Constructor kwargs for a single
         :class:`~repro.serve.service.PipelineService`."""
@@ -195,8 +201,13 @@ def drive_closed_loop(config: Any = None, *, requests: int = 200,
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     from .registry import run_closed_loop
-    scenario = cfg.build_scenario()
-    svc = build_service(cfg, scenario=scenario if cfg.workers == 1 else None)
+    if cfg.workers == 1:
+        scenario = cfg.build_scenario()
+        svc = build_service(cfg, scenario=scenario)
+    else:
+        # the parent stays off JAX: the workers own the devices
+        scenario = cfg.build_traffic()
+        svc = build_service(cfg)
     explained = None
     fleet_report = None
     try:

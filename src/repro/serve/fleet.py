@@ -45,11 +45,24 @@ fails that request's future with the underlying error.
 in-flight work, flushes, closes its service — which refreshes the
 cache manifests (entry counts, access stats) on disk — reports its
 stats and exits 0.  ``repro serve --drain`` surfaces the exit codes.
+
+Devices
+-------
+A TPU chip belongs to one process.  The demux therefore never touches
+JAX (it generates traffic from the corpus alone and only relays
+frames), and on a TPU host each worker is pinned to one chip of its
+own: libtpu's visibility variables (:func:`chip_env`) go into the
+child's environment before it starts, so it never sees — or locks —
+the other chips.  A fleet larger than the host's chip count is refused
+up front, and a worker that cannot open its chip reports why and the
+fleet start fails at once instead of waiting out the start timeout.
 """
 from __future__ import annotations
 
 import dataclasses
+import glob
 import itertools
+import os
 import threading
 import time
 import zlib
@@ -60,7 +73,34 @@ from ..distrib.fault import RetryPolicy
 from .config import ServeConfig
 from .service import ServiceStats
 
-__all__ = ["FleetService", "fleet_worker_main"]
+__all__ = ["FleetService", "fleet_worker_main", "chip_env",
+           "host_tpu_chips"]
+
+#: serializes the parent-environment swap around a worker's start
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
+def host_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device files
+    without loading JAX; 0 when ``JAX_PLATFORMS`` excludes the TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """libtpu environment giving one process exactly TPU chip ``chip``
+    as a single-chip slice of its own (process and chip bounds 1x1x1).
+    The bounds are what let each worker load libtpu next to the others:
+    on a v5e host with ``TPU_VISIBLE_CHIPS`` alone, one of four
+    processes started and the rest failed on libtpu's host-wide lock
+    file.  The per-chip port keeps their runtime ports apart."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + chip)}
 
 
 def _qid_slot(qid: str, n: int) -> int:
@@ -72,7 +112,8 @@ def _qid_slot(qid: str, n: int) -> int:
 # worker process
 # ---------------------------------------------------------------------------
 
-def fleet_worker_main(conn, cfg: ServeConfig, worker_id: int) -> None:
+def fleet_worker_main(conn, cfg: ServeConfig, worker_id: int,
+                      chip: Optional[int] = None) -> None:
     """Entry point of one worker process (module-level: spawn pickles
     it by reference).  Protocol, parent → worker::
 
@@ -84,23 +125,17 @@ def fleet_worker_main(conn, cfg: ServeConfig, worker_id: int) -> None:
         ("stop",)           close immediately, exit 0
 
     and worker → parent additionally ``("ready", wid, warm_info)`` once
-    the local service is built (and warmed)."""
-    from .config import build_service
-    from .registry import warming_frame
+    the local service is built (and warmed), or ``("failed", wid,
+    reason)`` when it cannot start (no respawn: the cause persists).
 
-    cfg = cfg.single()
-    scenario = cfg.build_scenario()
-    svc = build_service(cfg, scenario=scenario)
-    warm_info: Dict[str, Any] = {}
-    if cfg.warm_start and cfg.cache_dir:
-        t0 = time.perf_counter()
-        frame = warming_frame(scenario, budget=cfg.warm_budget,
-                              seed=cfg.seed)
-        stats = svc.plan.warm(frame)
-        warm_info = {"queries_warmed": int(len(frame)),
-                     "warm_hits": int(stats.cache_hits),
-                     "warm_misses": int(stats.cache_misses),
-                     "warm_wall_s": round(time.perf_counter() - t0, 4)}
+    ``chip`` is the TPU chip the parent pinned this worker to through
+    :func:`chip_env`; the worker checks that JAX sees exactly it."""
+    try:
+        svc, warm_info = _start_worker(cfg, worker_id, chip)
+    except BaseException as e:           # noqa: BLE001 - relay, then die
+        conn.send(("failed", worker_id, f"{type(e).__name__}: {e}"))
+        conn.close()
+        raise
     send_lock = threading.Lock()
     outstanding = [0]
     done_cv = threading.Condition()
@@ -162,6 +197,36 @@ def fleet_worker_main(conn, cfg: ServeConfig, worker_id: int) -> None:
             return
 
 
+def _start_worker(cfg: ServeConfig, worker_id: int, chip: Optional[int]):
+    from ..caching import use_persistent_compile_cache
+    from .config import build_service
+    from .registry import warming_frame
+
+    use_persistent_compile_cache()
+    if chip is not None:
+        import jax
+        devs = jax.devices()
+        if len(devs) != 1 or devs[0].platform != "tpu":
+            raise RuntimeError(
+                f"fleet worker {worker_id} was pinned to TPU chip {chip} "
+                f"(TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')})"
+                f" but JAX sees {devs}")
+    cfg = cfg.single()
+    scenario = cfg.build_scenario()
+    svc = build_service(cfg, scenario=scenario)
+    warm_info: Dict[str, Any] = {}
+    if cfg.warm_start and cfg.cache_dir:
+        t0 = time.perf_counter()
+        frame = warming_frame(scenario, budget=cfg.warm_budget,
+                              seed=cfg.seed)
+        stats = svc.plan.warm(frame)
+        warm_info = {"queries_warmed": int(len(frame)),
+                     "warm_hits": int(stats.cache_hits),
+                     "warm_misses": int(stats.cache_misses),
+                     "warm_wall_s": round(time.perf_counter() - t0, 4)}
+    return svc, warm_info
+
+
 # ---------------------------------------------------------------------------
 # demux (parent) side
 # ---------------------------------------------------------------------------
@@ -169,20 +234,26 @@ def fleet_worker_main(conn, cfg: ServeConfig, worker_id: int) -> None:
 class _Worker:
     """Parent-side handle of one worker process."""
 
-    __slots__ = ("id", "proc", "conn", "send_lock", "ready", "drained",
-                 "alive", "drain_stats", "warm_info", "exit_code")
+    __slots__ = ("id", "proc", "conn", "chip", "send_lock", "ready",
+                 "drained", "reaped", "alive", "drain_stats", "warm_info",
+                 "exit_code", "start_error")
 
-    def __init__(self, wid: int, proc, conn):
+    def __init__(self, wid: int, proc, conn, chip: Optional[int]):
         self.id = wid
         self.proc = proc
         self.conn = conn
+        self.chip = chip
         self.send_lock = threading.Lock()
         self.ready = threading.Event()
         self.drained = threading.Event()
+        #: set by the reader thread once it has joined the process and
+        #: recorded ``exit_code`` — the one thread that reaps it
+        self.reaped = threading.Event()
         self.alive = True
         self.drain_stats: Optional[Dict[str, Any]] = None
         self.warm_info: Dict[str, Any] = {}
         self.exit_code: Optional[int] = None
+        self.start_error: Optional[str] = None
 
     def send(self, payload) -> None:
         with self.send_lock:
@@ -223,6 +294,13 @@ class FleetService:
         self._draining = False
         self._closed = False
         self._drain_report: Optional[Dict[str, Any]] = None
+        self._start_failures: List[str] = []
+        self._chips = host_tpu_chips()
+        self._held_chips: set = set()
+        if self._chips and self.config.workers > self._chips:
+            raise ValueError(
+                f"a fleet on a TPU host runs one worker per chip: "
+                f"{self.config.workers} workers > {self._chips} chips")
         import multiprocessing as mp
         self._ctx = mp.get_context("spawn")
         for _ in range(self.config.workers):
@@ -230,16 +308,40 @@ class FleetService:
         self._wait_ready(start_timeout)
 
     # -- worker lifecycle ----------------------------------------------------
+    def _take_chip(self) -> Optional[int]:
+        """Reserve the lowest chip no live worker holds (None off-TPU);
+        released once the holder's process has exited."""
+        if not self._chips:
+            return None
+        with self._lock:
+            chip = min(set(range(self._chips)) - self._held_chips)
+            self._held_chips.add(chip)
+        return chip
+
     def _spawn(self) -> "_Worker":
         wid = next(self._wids)
+        chip = self._take_chip()
         parent_conn, child_conn = self._ctx.Pipe(duplex=True)
         proc = self._ctx.Process(
             target=fleet_worker_main,
-            args=(child_conn, self.config, wid),
+            args=(child_conn, self.config, wid, chip),
             name=f"fleet-worker-{wid}", daemon=True)
-        proc.start()
+        env = chip_env(chip) if chip is not None else {}
+        with _SPAWN_ENV_LOCK:
+            # the child inherits the environment at exec, before it
+            # imports JAX; the parent's own is restored right after
+            saved = {k: os.environ.get(k) for k in env}
+            os.environ.update(env)
+            try:
+                proc.start()
+            finally:
+                for k, v in saved.items():
+                    if v is None:
+                        os.environ.pop(k, None)
+                    else:
+                        os.environ[k] = v
         child_conn.close()               # parent keeps its end only
-        w = _Worker(wid, proc, parent_conn)
+        w = _Worker(wid, proc, parent_conn, chip)
         with self._lock:
             self._workers[wid] = w
         t = threading.Thread(target=self._reader, args=(w,),
@@ -255,6 +357,10 @@ class FleetService:
                 pending = [w for w in self._workers.values()
                            if w.alive and not w.ready.is_set()]
                 n_alive = sum(w.alive for w in self._workers.values())
+                failed = self._start_failures[:1]
+            if failed:
+                self.close(drain=False)
+                raise RuntimeError(f"fleet startup failed: {failed[0]}")
             if n_alive == 0:
                 raise RuntimeError(
                     "fleet startup failed: every worker process exited "
@@ -277,6 +383,8 @@ class FleetService:
             if kind == "ready":
                 w.warm_info = msg[2]
                 w.ready.set()
+            elif kind == "failed":
+                w.start_error = msg[2]
             elif kind == "res":
                 self._resolve(msg[1], msg[2], None)
             elif kind == "err":
@@ -288,18 +396,26 @@ class FleetService:
 
     def _on_worker_exit(self, w: _Worker) -> None:
         with self._lock:
+            if w.start_error is not None:
+                self._start_failures.append(
+                    f"worker {w.id} (chip {w.chip}): {w.start_error}")
             w.alive = False
             self._workers.pop(w.id, None)
             orphaned = [rid for rid, e in self._inflight.items()
                         if e["worker"] == w.id]
         w.proc.join(timeout=10.0)
         w.exit_code = w.proc.exitcode
+        w.reaped.set()
+        with self._lock:
+            self._held_chips.discard(w.chip)
         if self._draining or self._closed or w.drained.is_set():
             return
-        # unexpected death: respawn warm (bounded), requeue the
-        # orphaned accepted requests onto survivors
+        # unexpected death: respawn warm (bounded; not after a start
+        # failure, which would repeat), requeue the orphaned accepted
+        # requests onto survivors
         with self._lock:
-            may_respawn = self.respawns < self._max_respawns
+            may_respawn = (w.start_error is None
+                           and self.respawns < self._max_respawns)
             if may_respawn:
                 self.respawns += 1
                 attempt = self.respawns
@@ -428,11 +544,11 @@ class FleetService:
         deadline = time.monotonic() + timeout
         for w in workers:
             w.drained.wait(max(0.0, deadline - time.monotonic()))
-            w.proc.join(timeout=max(0.1, deadline - time.monotonic()))
-            if w.proc.is_alive():        # refuse to hang: escalate
-                w.proc.terminate()
-                w.proc.join(timeout=5.0)
-            w.exit_code = w.proc.exitcode
+            # the reader thread reaps the process (two threads waiting
+            # on one pid can lose its exit status)
+            if not w.reaped.wait(max(0.1, deadline - time.monotonic())):
+                w.proc.terminate()       # refuse to hang: escalate
+                w.reaped.wait(15.0)
         per_worker = [w.drain_stats for w in workers
                       if w.drain_stats is not None]
         hits = sum(int(s["online"]["cache_hits"]) for s in per_worker)

@@ -38,14 +38,15 @@ from ..core.frame import ColFrame
 from ..core.pipeline import Transformer
 
 __all__ = ["ServeScenario", "SERVE_PIPELINES", "SimulatedLatency",
-           "build_scenario", "run_closed_loop", "warming_frame"]
+           "build_scenario", "build_traffic", "run_closed_loop",
+           "warming_frame"]
 
 
 @dataclass
 class ServeScenario:
     """A servable pipeline plus the topics that generate its traffic."""
     name: str
-    pipeline: Transformer
+    pipeline: Optional[Transformer]      # None: traffic only (build_traffic)
     topics: ColFrame                     # Q(qid, query) request pool
     description: str = ""
     #: extra per-request row columns keyed by qid (e.g. doc text for
@@ -53,10 +54,16 @@ class ServeScenario:
     request_extra: Dict[str, Dict[str, Any]] = field(default_factory=dict)
 
 
+def _mono_config():
+    """Widths of the mono cross-encoder every reranking scenario uses."""
+    from ..models.cross_encoder import EncoderConfig
+    return EncoderConfig(n_layers=2, d_model=64, n_heads=4, d_ff=128,
+                         vocab_size=8192, max_len=32)
+
+
 def _encoder():
-    from ..models.cross_encoder import EncoderConfig, MonoScorer
-    return MonoScorer(EncoderConfig(n_layers=2, d_model=64, n_heads=4,
-                                    d_ff=128, vocab_size=8192, max_len=32))
+    from ..models.cross_encoder import MonoScorer
+    return MonoScorer(_mono_config())
 
 
 def _build_bm25(*, scale: float, cutoff: int, num_results: int,
@@ -87,25 +94,29 @@ def _build_bm25_mono(*, scale: float, cutoff: int, num_results: int,
                     f">> text_loader >> mono scorer")
 
 
+def _mono_request_extra(corpus, seed: int) -> Dict[str, Dict[str, Any]]:
+    """Per-qid doc text the scorer-only scenario's requests carry."""
+    docs = corpus.docs
+    rng = np.random.default_rng(seed)
+    extra: Dict[str, Dict[str, Any]] = {}
+    n = min(len(docs), 200)
+    for qid in corpus.get_topics()["qid"].tolist():
+        d = int(rng.integers(0, n))
+        extra[str(qid)] = {"docno": str(docs["docno"][d]),
+                           "text": str(docs["text"][d])}
+    return extra
+
+
 def _build_mono(*, scale: float, cutoff: int, num_results: int,
                 seed: int) -> ServeScenario:
     from ..ir import msmarco_like
     corpus = msmarco_like(1, scale=scale, seed=seed)
-    docs = corpus.docs
-    rng = np.random.default_rng(seed)
-    topics = corpus.get_topics()
-    extra: Dict[str, Dict[str, Any]] = {}
-    n = min(len(docs), 200)
-    for qid in topics["qid"].tolist():
-        d = int(rng.integers(0, n))
-        extra[str(qid)] = {"docno": str(docs["docno"][d]),
-                           "text": str(docs["text"][d])}
     return ServeScenario(
         name="mono",
         pipeline=_encoder(),
-        topics=topics,
+        topics=corpus.get_topics(),
         description="bare pointwise scorer (requests carry doc text)",
-        request_extra=extra)
+        request_extra=_mono_request_extra(corpus, seed))
 
 
 def _dense_retriever(corpus, *, num_results: int, seed: int):
@@ -214,6 +225,26 @@ def build_scenario(name: str, *, scale: float = 0.05, cutoff: int = 10,
                        f"{sorted(SERVE_PIPELINES)}") from None
     return builder(scale=scale, cutoff=cutoff, num_results=num_results,
                    seed=seed)
+
+
+def build_traffic(name: str, *, scale: float = 0.05,
+                  seed: int = 0) -> ServeScenario:
+    """The request pool of a named scenario without its pipeline: the
+    topics (and per-qid request extras) come from the corpus alone, so
+    a fleet's parent can generate traffic without touching JAX — the
+    device belongs to the worker processes.  ``pipeline`` is ``None``.
+    """
+    from ..ir import msmarco_like
+    if name not in SERVE_PIPELINES:
+        raise KeyError(f"unknown serving pipeline {name!r}; known: "
+                       f"{sorted(SERVE_PIPELINES)}")
+    corpus = msmarco_like(1, scale=scale, seed=seed)
+    extra = _mono_request_extra(corpus, seed) if name == "mono" else {}
+    return ServeScenario(name=name, pipeline=None,
+                         topics=corpus.get_topics(),
+                         description=f"{name} request pool (the pipeline "
+                                     f"is built in the worker processes)",
+                         request_extra=extra)
 
 
 def warming_frame(scenario: ServeScenario, *,

@@ -1,4 +1,9 @@
-"""TPU adaptations: bucketed miss execution + CompileCache."""
+"""TPU adaptations: bucketed miss execution + CompileCache, and where
+JAX's persistent compilation cache is placed."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +12,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.caching import BucketedRunner, CompileCache, bucket_size, \
     pad_batch
+from repro.caching.compile_cache import DEFAULT_JAX_CACHE_DIR
 
 
 def test_bucket_size_powers_of_two():
@@ -67,3 +73,33 @@ def test_compile_cache_reuses_executables():
     # same shapes under a different name -> separate entry
     cc.call("g", f, x)
     assert cc.stats.compile_misses == 3
+
+
+_PLACE_AND_COMPILE = (
+    "import os, jax, jax.numpy as jnp\n"
+    "from repro.caching import use_persistent_compile_cache\n"
+    "print(use_persistent_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64))).block_until_ready()\n")
+
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env-set", "unset"])
+def test_persistent_compile_cache_placement(tmp_path, env_set):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own reading stands and
+    compiled programs land there; unset, the helper picks the one fixed
+    path in the checkout (never a temp name, pid or time)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    want = DEFAULT_JAX_CACHE_DIR
+    if env_set:
+        want = str(tmp_path / "outside")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    p = subprocess.run([sys.executable, "-c", _PLACE_AND_COMPILE],
+                       capture_output=True, text=True, env=env, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.split() == [want, want]
+    assert want == DEFAULT_JAX_CACHE_DIR or os.listdir(want)
+    assert DEFAULT_JAX_CACHE_DIR == os.path.join(root, ".jax_cache")

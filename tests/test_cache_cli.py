@@ -225,8 +225,7 @@ def test_export_requires_manifest(tmp_path):
 @pytest.mark.slow
 def test_python_m_repro_cli_verify(cache_root):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src"),
-           "REPRO_PROVENANCE_HASH": "host"}
+    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src")}
     p = subprocess.run([sys.executable, "-m", "repro.cli", "cache",
                         "verify", str(cache_root)],
                        capture_output=True, text=True, env=env, timeout=180)

@@ -151,3 +151,64 @@ def test_fleet_warm_start_zero_misses(tmp_path):
         assert report["online"]["cache_misses"] == 0     # no cold misses
         assert report["online"]["cache_hits"] > 0
         assert set(report["exit_codes"].values()) == {0}
+
+
+# -- devices: one chip per worker, the parent off JAX ------------------------
+
+def test_chip_env_pins_one_chip():
+    """Worker i's libtpu environment shows it chip i alone, as a
+    single-chip slice with a runtime port of its own."""
+    from repro.serve.fleet import chip_env
+    envs = [chip_env(i) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+
+
+def test_fleet_refuses_more_workers_than_chips(monkeypatch):
+    import repro.serve.fleet as fleet
+    monkeypatch.setattr(fleet, "host_tpu_chips", lambda: 2)
+    with pytest.raises(ValueError, match="one worker per chip"):
+        FleetService(_cfg(workers=3))
+
+
+def test_pinned_worker_without_its_chip_fails_fast(monkeypatch):
+    """A worker pinned to a chip JAX cannot give it fails at start with
+    the reason, well before the start timeout; the chip variables
+    reached the child and the parent's environment is restored."""
+    import time
+
+    import repro.serve.fleet as fleet
+    monkeypatch.setattr(fleet, "host_tpu_chips", lambda: 1)
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="pinned to TPU chip 0") as err:
+        FleetService(_cfg(workers=1), start_timeout=300.0)
+    assert time.monotonic() - t0 < 120
+    assert "TPU_VISIBLE_CHIPS=0" in str(err.value)
+    assert "TPU_VISIBLE_CHIPS" not in os.environ
+
+
+def test_fleet_parent_initialises_no_backend(tmp_path):
+    """drive_closed_loop with workers > 1 generates traffic from the
+    corpus alone: the parent never initialises a JAX backend, even for
+    a scenario whose pipeline runs on the device."""
+    import subprocess
+    import sys
+    script = (
+        "from repro.serve import ServeConfig, drive_closed_loop\n"
+        "cfg = ServeConfig(pipeline='dense', scale=0.02, cutoff=5,\n"
+        "                  num_results=20, max_batch=4, max_wait_ms=0.0,\n"
+        "                  exec_workers=1, warm_start=False, workers=2,\n"
+        f"                  cache_dir={str(tmp_path)!r})\n"
+        "rec = drive_closed_loop(cfg, requests=20, clients=2, drain=True)\n"
+        "from jax._src import xla_bridge\n"
+        "print(rec['requests'], rec['drained'],\n"
+        "      xla_bridge.backends_are_initialized())\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src")}
+    p = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.split()[-3:] == ["20", "True", "False"]

@@ -8,13 +8,15 @@ Acceptance coverage:
   unaffected nodes);
 * ``repro cache verify``-style manifest loading detects hand-corrupted
   manifests via the content checksum;
-* fingerprinting is deterministic across processes (subprocess test);
-* the kernel digest and its pure-Python fallback agree bit-for-bit.
+* fingerprinting is deterministic across processes (subprocess test)
+  and never initialises a JAX backend;
+* the host digest and the cachekey_hash kernel agree bit-for-bit.
 """
 import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 import repro.caching.provenance as prov
@@ -85,21 +87,19 @@ def test_canonical_bytes_distinguishes_types():
 
 
 def test_host_and_kernel_digests_agree():
-    """The pure-Python fallback must be bit-identical to the
-    cachekey_hash kernel digest."""
+    """The host digest must stay bit-identical to the cachekey_hash
+    kernel's, so cache directories keyed by either stay valid."""
+    from repro.kernels.cachekey_hash import cachekey_hash_op
     data = canonical_bytes(("shared", 7, 2.5, ("nested", None)))
-    saved = prov._DIGEST_IMPL
-    try:
-        prov._DIGEST_IMPL = prov._host_digest
-        host = prov.digest_bytes(data)
-        try:
-            kernel = prov._kernel_digest_factory()
-        except Exception:
-            pytest.skip("cachekey_hash kernel unavailable")
-        prov._DIGEST_IMPL = kernel
-        assert prov.digest_bytes(data) == host
-    finally:
-        prov._DIGEST_IMPL = saved
+    buf = len(data).to_bytes(8, "little") + data
+    buf += b"\x00" * ((-len(buf)) % 4)
+    words = np.frombuffer(buf, dtype="<u4")
+    words = np.concatenate([words, np.zeros(
+        (-len(words)) % prov._WORD_BUCKET, dtype="<u4")])
+    out = np.asarray(cachekey_hash_op(words.view(np.int32).reshape(1, -1)))
+    kernel = ((int(out[0, 0]) & 0xFFFFFFFF).to_bytes(4, "little")
+              + (int(out[0, 1]) & 0xFFFFFFFF).to_bytes(4, "little"))
+    assert prov.digest_bytes(data) == kernel.hex()
 
 
 @pytest.mark.slow
@@ -110,8 +110,7 @@ def test_fingerprint_deterministic_across_processes():
               "print(GenericTransformer(lambda x: x, 'named',"
               " params=(1, 2.5)).fingerprint())\n")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src"),
-           "REPRO_PROVENANCE_HASH": "host"}   # skip jax startup in children
+    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src")}
     outs = []
     for _ in range(2):
         p = subprocess.run([sys.executable, "-c", script],
@@ -120,8 +119,26 @@ def test_fingerprint_deterministic_across_processes():
         assert p.returncode == 0, p.stderr[-2000:]
         outs.append(p.stdout.split())
     assert outs[0] == outs[1]
-    # ... and identical to this process's value (kernel or host path)
+    # ... and identical to this process's value
     assert outs[0][0] == QueryExpander(2).fingerprint()
+
+
+def test_fingerprint_initialises_no_backend():
+    """Fingerprinting digests on the host: a fresh process that
+    fingerprints transformers has not initialised any JAX backend, so
+    it never takes a device another process needs."""
+    script = ("from repro.ir import QueryExpander\n"
+              "from repro.caching.provenance import combine_fingerprints\n"
+              "fp = QueryExpander(2).fingerprint()\n"
+              "combine_fingerprints(fp, 'node')\n"
+              "from jax._src import xla_bridge\n"
+              "print(xla_bridge.backends_are_initialized())\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "PYTHONPATH": os.path.join(root, "src")}
+    p = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, env=env, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.split()[-1] == "False"
 
 
 # -- manifests ----------------------------------------------------------------
