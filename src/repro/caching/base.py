@@ -25,6 +25,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from ..core import trace
 from ..core.pipeline import Transformer
 from .economics import AccessStats, CacheBudget, evict_entries
 from .provenance import CacheManifest, ManifestError, StaleCacheError
@@ -490,6 +491,7 @@ class CacheTransformer(Transformer):
         staging = self._staging
         writer = self._writer
 
+        @trace.spanned("cache.io")
         def fetch():
             want = todo
             if writer is not None:
@@ -511,6 +513,7 @@ class CacheTransformer(Transformer):
         if self._staging is not None:
             self._staging.discard()
 
+    @trace.spanned("cache.lookup")
     def _lookup_many(self, keys: Sequence[bytes]
                      ) -> Tuple[List[Optional[bytes]], int]:
         """Read ``keys`` through the data plane: the write-behind
@@ -555,6 +558,7 @@ class CacheTransformer(Transformer):
                 out[i] = v
         return out, prefetched
 
+    @trace.spanned("cache.lookup")
     def _recheck_many(self, keys: Sequence[bytes]
                       ) -> List[Optional[bytes]]:
         """The locked miss-path recheck: the write-behind overlay (a
@@ -572,6 +576,7 @@ class CacheTransformer(Transformer):
                 out[i] = v
         return out
 
+    @trace.spanned("cache.store")
     def _store_many(self, items: Sequence[Tuple[bytes, bytes]]) -> None:
         """Miss-path put: enqueue on the write-behind writer when one
         is live, else write through synchronously.  Called inside the
@@ -584,6 +589,7 @@ class CacheTransformer(Transformer):
         else:
             self._backend.put_many(items)
 
+    @trace.spanned("cache.store")
     def _write_barrier(self) -> None:
         """Durability barrier before the backend's cross-process lock is
         released (see ``WriteBehindWriter.barrier``): other processes'
@@ -594,6 +600,7 @@ class CacheTransformer(Transformer):
         if self._writer is not None:
             self._writer.barrier()
 
+    @trace.spanned("cache.store")
     def _drain_writes(self) -> None:
         """Synchronously flush pending write-behind state (flush points:
         ``close()``, ``drain()``, manifest refresh, eviction, store
@@ -622,6 +629,7 @@ class CacheTransformer(Transformer):
         return t
 
     # -- lifecycle -----------------------------------------------------------
+    @trace.spanned("cache.store")
     def close(self) -> None:
         if self._closed:
             return

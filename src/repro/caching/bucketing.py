@@ -16,6 +16,8 @@ from typing import Any, Callable, Dict, Sequence, Tuple
 
 import numpy as np
 
+from ..core import trace
+
 __all__ = ["bucket_size", "pad_batch", "BucketedRunner",
            "DEVICE_BATCH_FLOOR"]
 
@@ -72,8 +74,13 @@ class BucketedRunner:
             chunk = [a[lo:lo + self.max_bucket] for a in arrays]
             m = chunk[0].shape[0]
             b = bucket_size(m, floor=self.floor, ceiling=self.max_bucket)
-            padded = [pad_batch(a, b) for a in chunk]
-            self.shapes_issued[b] = self.shapes_issued.get(b, 0) + 1
-            out = np.asarray(self.fn(*padded))
+            with trace.span("encoder.call", rows=m, bucket=b):
+                padded = [pad_batch(a, b) for a in chunk]
+                self.shapes_issued[b] = self.shapes_issued.get(b, 0) + 1
+                out = np.asarray(self.fn(*padded))
+            # the first array holds the token ids: real ones are nonzero
+            trace.count("encoder.tokens",
+                        lambda: int(np.count_nonzero(chunk[0])))
+            trace.count("encoder.slots", padded[0].size)
             outs.append(out[:m])
         return np.concatenate(outs, axis=0)

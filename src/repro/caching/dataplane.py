@@ -50,6 +50,8 @@ import threading
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
+from ..core import trace
+
 __all__ = [
     "io_pool", "prefetch_default", "write_behind_default",
     "StagingMap", "WriteBehindWriter",
@@ -254,6 +256,7 @@ class WriteBehindWriter:
             self._task_live = True
         io_pool().submit(self._background_drain)
 
+    @trace.spanned("cache.io")
     def _background_drain(self) -> None:
         try:
             self._drain()

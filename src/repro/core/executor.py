@@ -48,6 +48,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .frame import ColFrame
+from . import trace
 from .ir import IRNode, PlanGraph
 from .precompute import _run_stage
 
@@ -198,19 +199,28 @@ def _exec_with_probe(node: IRNode, probe_frame: ColFrame,
     from the warm store keyed off ``probe_frame``; on any miss, execute
     the deferred chain to build the node's real input, then run the
     memoized stage normally."""
-    t0 = time.perf_counter()
-    out = node.cache.serve_from_store(probe_frame)
+    with trace.timed("plan.node", node=node.label) as probe:
+        out = node.cache.serve_from_store(probe_frame)
     if out is not None:
-        rec.add(node.label, shard, t0, time.perf_counter())
+        rec.add(node.label, shard, probe.t0, probe.t1)
         return out
     v = probe_frame
     for ch in node.inline_chain:
-        t1 = time.perf_counter()
-        v = _exec_node(ch, [v], batch_size)
-        rec.add(ch.label, shard, t1, time.perf_counter())
-    t1 = time.perf_counter()
-    out = _exec_node(node, [v], batch_size)
-    rec.add(node.label, shard, t1, time.perf_counter())
+        v = _timed_exec(ch, [v], batch_size, shard, rec)
+    # the failed probe was this node's work too: its record carries the
+    # probe's seconds, so node times and the plan.node spans agree
+    return _timed_exec(node, [v], batch_size, shard, rec,
+                       extra_s=probe.t1 - probe.t0)
+
+
+def _timed_exec(node: IRNode, ins: List[ColFrame],
+                batch_size: Optional[int], shard: int, rec: _Recorder,
+                extra_s: float = 0.0) -> ColFrame:
+    """Run one node inside a ``plan.node`` span, and give ``rec`` the
+    span's own clock readings (less ``extra_s`` at the start)."""
+    with trace.timed("plan.node", node=node.label) as t:
+        out = _exec_node(node, ins, batch_size)
+    rec.add(node.label, shard, t.t0 - extra_s, t.t1)
     return out
 
 
@@ -294,10 +304,8 @@ def run_sequential(graph: PlanGraph, frame: ColFrame,
             out = _exec_with_probe(node, evaluate(node.probe_input),
                                    batch_size, 0, rec)
         else:
-            ins = [evaluate(i) for i in node.inputs]
-            t0 = time.perf_counter()
-            out = _exec_node(node, ins, batch_size)
-            rec.add(node.label, 0, t0, time.perf_counter())
+            out = _timed_exec(node, [evaluate(i) for i in node.inputs],
+                              batch_size, 0, rec)
         results[node.id] = out
         if pf is not None:
             pf.node_ready(node.id, out)
@@ -410,10 +418,9 @@ def run_concurrent(graph: PlanGraph, frame: ColFrame,
             out = _exec_with_probe(node, results[(node.probe_input.id, s)],
                                    batch_size, s, rec)
         else:
-            ins = [results[(i.id, s)] for i in node.inputs]
-            t0 = time.perf_counter()
-            out = _exec_node(node, ins, batch_size)
-            rec.add(node.label, s, t0, time.perf_counter())
+            out = _timed_exec(node, [results[(i.id, s)]
+                                     for i in node.inputs],
+                              batch_size, s, rec)
         results[(node.id, s)] = out
 
     try:
@@ -927,7 +934,8 @@ class StreamingExecutor:
                     self.batch_size, s, _NULL_RECORDER)
             else:
                 ins = [self._results[(i.id, s)] for i in node.inputs]
-                out = _exec_node(node, ins, self.batch_size)
+                out = _timed_exec(node, ins, self.batch_size, s,
+                                  _NULL_RECORDER)
             dt_ms = (time.perf_counter() - t0) * 1000.0
         except BaseException as e:
             self._fail_batch(s, e)

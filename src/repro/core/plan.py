@@ -57,6 +57,7 @@ from .frame import ColFrame
 from .ir import IRNode, PlanGraph, lower, plan_size, render_explain
 from .pipeline import Transformer, pipeline_hash
 from .precompute import PrecomputeStats, longest_common_prefix
+from . import trace
 from .rewrite import (PLACEMENT_PASSES, POST_MEMO_PASSES, PassStats,
                       resolve_passes, run_pass)
 
@@ -191,6 +192,7 @@ class ExecutionPlan:
         globally by ``REPRO_PREFETCH=0`` / ``REPRO_WRITE_BEHIND=0``.
     """
 
+    @trace.spanned("plan.build")
     def __init__(self, pipelines: Sequence[Transformer], *,
                  cache_dir: Optional[str] = None,
                  cache_backend: Optional[str] = None,
@@ -630,6 +632,7 @@ class ExecutionPlan:
         return self.graph.n_nodes()
 
     # -- execution ---------------------------------------------------------
+    @trace.spanned("plan.run")
     def run(self, queries: Any, *, batch_size: Optional[int] = None,
             n_shards: Optional[int] = None,
             max_workers: Optional[int] = None,
@@ -760,13 +763,6 @@ class ExecutionPlan:
         stats.cache_misses = misses - cache_base[1]
         stats.cache_prefetched = prefetched - cache_base[2]
         stats.wall_time_s = time.perf_counter() - t0
-        if stats.n_shards > 1 and stats.wall_time_s > 0 \
-                and stats.shard_times_s \
-                and stats.speedup_vs_sequential is None:
-            # sum of per-shard busy spans ≈ the sequential wall this run
-            # would have taken; benchmarks overwrite with a measured ratio
-            stats.speedup_vs_sequential = round(
-                sum(stats.shard_times_s) / stats.wall_time_s, 2)
         self.stats = stats
         self._record_run(stats)
 

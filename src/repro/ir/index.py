@@ -16,6 +16,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from ..core import trace
 from ..core.frame import ColFrame
 from ..core.pipeline import Indexer, Transformer, add_ranks
 from .tokenizer import WordTokenizer
@@ -149,6 +150,7 @@ class BM25Retriever(Transformer):
         return BM25Retriever(self.index, k1=self.k1, b=self.b,
                              num_results=int(k), name=self.name)
 
+    @trace.spanned("bm25.search")
     def score_query(self, query: str) -> Tuple[np.ndarray, np.ndarray]:
         """Returns (doc_indices, scores) of the top-num_results docs."""
         idx = self.index

@@ -30,6 +30,7 @@ import numpy as np
 
 from ..caching.bucketing import DEVICE_BATCH_FLOOR, BucketedRunner
 from ..caching.compile_cache import default_compile_cache
+from ..core import trace
 from ..core.frame import ColFrame
 from ..core.pipeline import Transformer, add_ranks
 from ..ir.tokenizer import HashTokenizer
@@ -118,7 +119,17 @@ def encoder_score(params: Dict, tokens: jnp.ndarray,
     return jnp.einsum("bd,do->bo", pooled, params["w_score"])[:, 0]
 
 
+def _scoped_score(role: str, params: Dict, tokens: jnp.ndarray,
+                  cfg: EncoderConfig) -> jnp.ndarray:
+    with jax.named_scope(role):
+        return encoder_score(params, tokens, cfg)
+
+
 class _EncoderBase(Transformer):
+    #: the stage's role (``mono``, ``duo``), naming its tokenizing spans
+    #: and its programs' ops
+    role: str
+
     def __init__(self, cfg: EncoderConfig, seed: int = 0):
         self.cfg = cfg
         self.seed = seed
@@ -128,23 +139,30 @@ class _EncoderBase(Transformer):
         self.invocations = 0     # pairs actually scored (cache accounting)
 
         def _score(tokens):
+            # the jitted lambda keeps its name (``jit__lambda``) and its
+            # persistent-cache key; the scope names the ops by role
             return default_compile_cache.call(
                 f"{type(self).__name__}:{cfg.name}",
-                lambda t: encoder_score(self.params, t, self.cfg), tokens)
+                lambda t: _scoped_score(self.role, self.params, t, self.cfg),
+                tokens)
 
         self._runner = BucketedRunner(_score, floor=DEVICE_BATCH_FLOOR,
                                       max_bucket=1024)
 
     def _score_pairs(self, queries, texts) -> np.ndarray:
-        toks = np.stack([
-            self.tokenizer.encode_pair(q, t, self.cfg.max_len)
-            for q, t in zip(queries, texts)])
+        with trace.span("encoder.tokenize", role=self.role,
+                        pairs=len(queries)):
+            toks = np.stack([
+                self.tokenizer.encode_pair(q, t, self.cfg.max_len)
+                for q, t in zip(queries, texts)])
         self.invocations += len(queries)
         return np.asarray(self._runner(toks), dtype=np.float64)
 
 
 class MonoScorer(_EncoderBase):
     """Pointwise neural reranker (R→R).  Cache-safe (paper §4.2)."""
+
+    role = "mono"
 
     input_columns = frozenset({"qid", "query", "docno", "text"})
     key_columns = ("query", "docno")
@@ -166,6 +184,8 @@ class MonoScorer(_EncoderBase):
 class DuoScorer(_EncoderBase):
     """Pairwise reranker (R→R): score of d_i depends on the other
     candidates (sum over j of s(d_i ≻ d_j)).  NOT cacheable — §5."""
+
+    role = "duo"
 
     input_columns = frozenset({"qid", "query", "docno", "text"})
     cacheable = False

@@ -78,12 +78,6 @@ class ServiceStats:
 
     # -- views ---------------------------------------------------------------
     @property
-    def latencies_ms(self) -> List[float]:
-        """Snapshot of the latency reservoir (compatibility view of the
-        old unbounded list)."""
-        return self.latencies.snapshot()
-
-    @property
     def hit_rate(self) -> float:
         lookups = self.cache_hits + self.cache_misses
         return self.cache_hits / lookups if lookups else 0.0
