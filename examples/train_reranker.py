@@ -45,8 +45,8 @@ neg = [(q_text[q], text[docnos[rng.integers(len(docnos))]])
        for q in qrels["qid"].tolist()]
 pairs = pos + neg
 labels = np.array([1.0] * len(pos) + [0.0] * len(neg), np.float32)
-toks = np.stack([scorer.tokenizer.encode_pair(q, t, cfg.max_len)
-                 for q, t in pairs])
+toks = scorer.tokenizer.encode_pairs([q for q, _ in pairs],
+                                    [t for _, t in pairs], cfg.max_len)
 
 # ---- train with the shared substrate
 params = init_params(encoder_param_specs(cfg), jax.random.key(0))

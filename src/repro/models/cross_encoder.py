@@ -152,9 +152,8 @@ class _EncoderBase(Transformer):
     def _score_pairs(self, queries, texts) -> np.ndarray:
         with trace.span("encoder.tokenize", role=self.role,
                         pairs=len(queries)):
-            toks = np.stack([
-                self.tokenizer.encode_pair(q, t, self.cfg.max_len)
-                for q, t in zip(queries, texts)])
+            toks = self.tokenizer.encode_pairs(queries, texts,
+                                               self.cfg.max_len)
         self.invocations += len(queries)
         return np.asarray(self._runner(toks), dtype=np.float64)
 
@@ -210,12 +209,11 @@ class DuoScorer(_EncoderBase):
                 out_parts.append(grp.assign(
                     score=np.zeros(n, dtype=np.float64)))
                 continue
-            qs, ts = [], []
             pairs = [(i, j) for i in range(n) for j in range(n) if i != j]
-            for i, j in pairs:
-                qs.append(query)
-                ts.append(texts[i] + " [VS] " + texts[j])
-            s = self._score_pairs(qs, ts)
+            # each pair's document side is its two passages, so each
+            # passage is tokenized once per topic
+            s = self._score_pairs([query] * len(pairs),
+                                  [(texts[i], texts[j]) for i, j in pairs])
             agg = np.zeros(n, dtype=np.float64)
             for (i, j), v in zip(pairs, s):
                 agg[i] += v          # wins of i over j

@@ -24,7 +24,8 @@ CUTS = (5, 8)
 SPANS = {"plan.build", "plan.run", "plan.node", "experiment.evaluate",
          "cache.lookup", "cache.store", "bm25.search", "encoder.tokenize",
          "encoder.call"}
-COUNTERS = {"encoder.tokens", "encoder.slots"}
+COUNTERS = {"encoder.tokens", "encoder.slots", "tokenizer.sides",
+            "tokenizer.strings"}
 
 
 def _systems():
@@ -131,6 +132,7 @@ def test_a_plan_records_every_span_and_counter(tmp_path):
     c = s["counters"]
     assert 0 < c["encoder.tokens"] < c["encoder.slots"]
     assert c["encoder.slots"] % CE.max_len == 0
+    assert 0 < c["tokenizer.strings"] < c["tokenizer.sides"]
     # the planner's node times come from the spans' own readings
     st = res.precompute
     assert sum(st.node_exec_counts.values()) == node["n"]
