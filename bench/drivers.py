@@ -20,10 +20,6 @@ import numpy as np
 
 from . import gen
 
-#: the buckets the program's encoders pad a batch to (64 rows up to 1024)
-ENCODER_BUCKETS = (64, 128, 256, 512, 1024)
-
-
 @dataclass
 class Run:
     window_s: float = 0.0
@@ -56,26 +52,12 @@ def rows_of(frame) -> List:
                 frame["rank"].tolist())]
 
 
-def warm_encoders(world, queries: gen.Queries) -> None:
-    """Run each encoder stage once at each bucket the window can reach."""
-    texts = world.texts
-    c = world.corpus
+def warm_stages(world, queries: gen.Queries) -> None:
+    """Run each stage at every shape the window can reach, as its kind's
+    module says."""
     for name, stage in world.stages.items():
-        kind = world.cfg["stages"][name]["kind"]
-        if kind == "mono":
-            for b in ENCODER_BUCKETS:
-                rows = [{"qid": "w", "query": queries.texts[i % len(queries.texts)],
-                         "docno": c.docnos[i], "text": texts[i]}
-                        for i in range(b)]
-                stage.transform(frame_rows(rows))
-        elif kind == "duo":
-            # a group of n docs makes n (n - 1) pairs: 10 -> bucket 128, 8 -> 64
-            m = stage.max_docs
-            for n in sorted({m, min(m, 8)}):
-                rows = [{"qid": "w", "query": queries.texts[0],
-                         "docno": c.docnos[i], "text": texts[i], "rank": i,
-                         "score": float(-i)} for i in range(n)]
-                stage.transform(frame_rows(rows))
+        world.kinds[name].warm(world, stage, world.cfg["stages"][name],
+                               queries)
 
 
 def queries_for(world, spec: Dict, n: int, seed: int, stream: int,
@@ -125,12 +107,12 @@ class GridDriver:
                           keep_results=True)
 
     def warm_up(self) -> None:
-        # the encoders' buckets are warmed one by one; a small grid run
+        # each stage's shapes are warmed one by one; a small grid run
         # warms the rest of the plan's path
         q = queries_for(self.world, self.t["queries"],
                         min(8, int(self.t["topics_per_iteration"])),
                         self.seed, 99, "warm.")
-        warm_encoders(self.world, q)
+        warm_stages(self.world, q)
         self._experiment(q)
 
     def window(self, seconds: float) -> Run:
@@ -228,9 +210,7 @@ class OpenLoopDriver:
     def warm_up(self) -> None:
         q = queries_for(self.world, self.t["queries"], 64, self.seed, 99,
                         "warm.")
-        warm_encoders(self.world, q)
-        if self.world.dense is not None:
-            self.world.dense.device_chunks()
+        warm_stages(self.world, q)
         rate = float(self.t["rate_per_s"])
         secs = float(self.t["warmup_s"])
         n = max(1, int(round(rate * secs)))

@@ -26,6 +26,11 @@ def encoder_flops(real_tokens: np.ndarray, L: int, d: int, F: int,
     return float(total)
 
 
+def encoder_weight_bytes(L: int, d: int, F: int) -> float:
+    """float32 bytes of one encoder pass's layer weights (read per call)."""
+    return 4.0 * L * (4 * d * d + 2 * d * F + 2 * d)
+
+
 def pair_tokens(query_words: int, passage_words: np.ndarray,
                 max_len: int) -> np.ndarray:
     """Real tokens of ``[CLS] query [SEP] passage`` inputs cut to

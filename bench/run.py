@@ -151,7 +151,8 @@ def run_cell(reg, args, peaks):
     from bench.check import checker
     from bench.drivers import DRIVERS
     from bench.world import World
-    world = World(cfg, args.seed)
+    compare = checker(traffic, cfg, reg.kind)
+    world = World(cfg, args.seed, reg.kind)
     log(f"[setup] world built at {time.perf_counter() - T0:.3f}s")
     driver = DRIVERS[traffic["driver"]](world, traffic, args.seed)
     driver.warm_up()
@@ -203,8 +204,7 @@ def run_cell(reg, args, peaks):
     gc.collect()
 
     t = time.perf_counter()
-    nums, work = checker(traffic, cfg)(cfg, traffic, corpus, run, args.seed,
-                                       args.control)
+    nums, work = compare(corpus, run, args.seed, args.control)
     log(f"[check] took {time.perf_counter() - t:.3f}s control={args.control}")
     log("[work] " + " ".join(f"{k}={v!r}" for k, v in work.items()))
     trace = None

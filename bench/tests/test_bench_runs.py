@@ -1,7 +1,9 @@
 """Whole runs of toy cells on the CPU: the harness past its look for a
 chip, with the real drivers, comparison and metric readers.  A sound run
 is correct; the lower-precision control and an answer altered where the
-program produces it are not."""
+program produces it are not.  ``toy.pw`` runs a scorer kind that the
+harness does not have, added to the toy tree as files and entries only."""
+import filecmp
 import os
 
 import numpy as np
@@ -11,7 +13,7 @@ from bench import run as bench_run
 from bench.registry import Registry
 from bench.tests import toy
 
-CELLS = ["toy.grid", "toy.rerank", "toy.dense"]
+CELLS = ["toy.grid", "toy.rerank", "toy.dense", "toy.pw"]
 
 
 @pytest.fixture(scope="module")
@@ -53,6 +55,7 @@ def _alter_scores(frame):
     ("toy.grid", "repro.models.cross_encoder.DuoScorer"),
     ("toy.rerank", "repro.models.cross_encoder.MonoScorer"),
     ("toy.dense", "repro.ir.dense.DenseRetriever"),
+    ("toy.pw", "repro.models.cross_encoder.MonoScorer"),
 ])
 def test_an_altered_answer_is_not_correct(reg, cell, target, monkeypatch):
     import importlib
@@ -63,3 +66,24 @@ def test_an_altered_answer_is_not_correct(reg, cell, target, monkeypatch):
                         lambda self, inp: _alter_scores(orig(self, inp)))
     res = bench_run.run_cell(reg, toy.args(cell, seed=4), toy.PEAKS)
     assert not res["correct"], res["check"]
+
+
+def test_the_toy_tree_adds_files_and_edits_none_of_the_harness(reg):
+    """The new kind is one new file beside byte-identical copies of the
+    repository's kinds, metric readers and peaks."""
+    for sub in ("kinds", "metrics"):
+        ours = {f for f in os.listdir(os.path.join(toy.BENCH, sub))
+                if f.endswith(".py")}
+        theirs = {f for f in os.listdir(os.path.join(reg.bench, sub))
+                  if f.endswith(".py")}
+        assert ours <= theirs
+        assert theirs - ours == ({"pw.py"} if sub == "kinds" else
+                                 {"toy_topics_per_iteration.grid.py"})
+        for f in ours:
+            assert filecmp.cmp(os.path.join(toy.BENCH, sub, f),
+                               os.path.join(reg.bench, sub, f),
+                               shallow=False), f
+    assert filecmp.cmp(os.path.join(toy.BENCH, "peaks.json"),
+                       os.path.join(reg.bench, "peaks.json"), shallow=False)
+    assert reg.config("toy-pw")["stages"]["pw"] == {"kind": "pw",
+                                                    "stream": 4}

@@ -195,6 +195,14 @@ def test_registry_finds_parts_by_name(tmp_path):
     assert reg.reader("toy_topics_per_iteration.grid")(rec) == 4.0
     with pytest.raises(KeyError):
         reg.peaks("TPU v0")
+    mono = reg.kind("mono")
+    assert mono.ROLE == "pointwise" and mono.STREAM == 1
+    assert reg.kind("mono") is mono
+    assert reg.kind("pw").ROLE == "pointwise"
+    with pytest.raises(KeyError, match=r"no stage kind 'colbert'; known: "
+                       r"\['bm25', 'dense', 'duo', 'mono', 'pw', "
+                       r"'text_loader'\]"):
+        reg.kind("colbert")
 
 
 def test_the_benchmark_names_a_reader_for_every_metric():
@@ -204,4 +212,6 @@ def test_the_benchmark_names_a_reader_for_every_metric():
     for w in reg.spec["workloads"]:
         assert reg.traffic(w["traffic"])["driver"] in ("grid", "open_loop")
         assert reg.limits(w["name"])
+        for st in reg.config(w["config"])["stages"].values():
+            assert reg.kind(st["kind"]).ROLE
     assert reg.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12

@@ -82,6 +82,72 @@ def read(r):
 '''
 
 
+#: A pointwise scorer kind that the harness does not have: the program's
+#: MonoScorer at the stream its stage entry names, with its own reference.
+#: ``make`` adds it as a later configuration would, by files and entries.
+PW_KIND = '''"""Toy pointwise kind: the program's MonoScorer at the
+configuration's BERT widths, weighted from its stage entry's stream."""
+import numpy as np
+
+from bench import bert, flops, weights
+from bench.drivers import frame_rows
+from bench.reference.encoder import run_blocks
+from bench.reference.tokens import stack
+
+ROLE = "pointwise"
+CORPUS = True
+
+
+def params(cfg, spec):
+    return bert.params(cfg, spec["stream"])
+
+
+def build(world, name, spec):
+    from repro.models.cross_encoder import MonoScorer
+    s = MonoScorer(bert.encoder_config(world.cfg, name))
+    weights.install(s, params(world.cfg, spec))
+    return s
+
+
+def warm(world, stage, spec, queries):
+    c = world.corpus
+    for b in (64, 128, 256, 512, 1024):
+        stage.transform(frame_rows([
+            {"qid": "w", "query": queries.texts[0], "docno": c.docnos[i],
+             "text": world.texts[i]} for i in range(b)]))
+
+
+def work(cfg, spec, real_tokens):
+    return bert.work(cfg, real_tokens)
+
+
+class Reference:
+    def __init__(self, cfg, spec, inputs):
+        self.S, self.corpus = cfg["max_len"], inputs.corpus
+        self.tok = bert.tokens(cfg, inputs)
+        self.params = params(cfg, spec)
+
+    def score(self, q, docs, precision):
+        docs = list(dict.fromkeys(int(d) for d in docs))
+        toks = stack([self.tok.pair(q, self.corpus.doc(d), self.S)
+                      for d in docs], self.S)
+        s = run_blocks(self.params, toks, head="score", precision=precision)
+        return dict(zip(docs, s.astype(np.float64)))
+
+    def real_tokens(self, q, groups):
+        docs = list(dict.fromkeys(int(d) for g in groups for d in g))
+        return flops.pair_tokens(len(q), self.corpus.lengths()[docs], self.S)
+'''
+PW_CONFIG = dict(CONFIGS["toy-rerank"], name="toy-pw", weight_seed=8,
+                 stages={"bm25": {"kind": "bm25", "k1": 1.2, "b": 0.75},
+                         "text_loader": {"kind": "text_loader"},
+                         "pw": {"kind": "pw", "stream": 4}})
+PW_TRAFFIC = dict(TRAFFIC["toy-grid"],
+                  systems="bm25 % {k} >> text_loader >> pw")
+PW_CELL = {"name": "toy.pw", "config": "toy-pw", "traffic": "toy-pw-grid",
+           "chips": 1, "why": "toy grid over a scorer kind added as files"}
+
+
 def _metric(name, unit, better, source, cells, layer=None, moves=None):
     m = {"name": name, "unit": unit, "better": better, "source": source,
          "workloads": cells}
@@ -137,8 +203,10 @@ def make(tmp: str, *, extra_metric: bool = True) -> str:
     bench = os.path.join(root, "bench")
     for sub in ("configs", "traffic", "limits"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    for sub in ("kinds", "metrics"):
+        shutil.copytree(os.path.join(BENCH, sub), os.path.join(bench, sub),
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(BENCH, "peaks.json"), bench)
     spec = {"command": ["python3", "bench/run.py"], "paths": ["bench"],
             "run_seconds": 1,
@@ -167,7 +235,34 @@ def make(tmp: str, *, extra_metric: bool = True) -> str:
         with open(os.path.join(bench, "limits", f"{w['name']}.json"),
                   "w") as f:
             json.dump(LIMITS, f)
+    _add_pw(root, bench)
     return root
+
+
+def _add_pw(root: str, bench: str) -> None:
+    """Add the ``pw`` kind and its cell as a configuration would: new files
+    (kind, configuration, mix, limits) and appended entries (the
+    configuration, the cell, and the cell in the grid metrics' lists)."""
+    files = {("kinds", "pw.py"): PW_KIND,
+             ("configs", "toy-pw.json"): json.dumps(PW_CONFIG),
+             ("traffic", "toy-pw-grid.json"): json.dumps(PW_TRAFFIC),
+             ("limits", "toy.pw.json"): json.dumps(
+                 dict(LIMITS, pw_err=LIMITS["mono_err"]))}
+    for (sub, name), text in files.items():
+        with open(os.path.join(bench, sub, name), "w") as f:
+            f.write(text)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "toy-pw", "source": "toy",
+                            "file": "bench/configs/toy-pw.json",
+                            "reduced": [], "why": "toy"})
+    spec["workloads"].append(PW_CELL)
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m.get("workloads") == GRID:
+            m["workloads"] = GRID + [PW_CELL["name"]]
+    with open(path, "w") as f:
+        json.dump(spec, f, indent=1)
 
 
 def args(workload: str, seed: int = 7, seconds: float = 1.0, trace: int = 0,

@@ -58,6 +58,18 @@ def encoder_params(enc: Dict, max_len: int, seed: int) -> Dict:
                  float(enc["initializer_range"]))
 
 
+def install(target, params) -> None:
+    """Give a program stage the benchmark's weights (through its public
+    ``params``), after checking that the layouts agree leaf by leaf."""
+    have = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)),
+                        target.params)
+    want = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), params)
+    if have != want:
+        raise ValueError(f"{type(target).__name__}: the program's weight "
+                         f"layout {have} differs from the benchmark's {want}")
+    target.params = params
+
+
 @partial(jax.jit, static_argnames=("rows", "dim"))
 def index_rows(key, rows: int, dim: int):
     """``rows`` unit vectors of ``dim`` float32 entries (the corpus side
