@@ -10,6 +10,8 @@ notes for DuoT5 (§5), it is **not amenable to caching**; it declares
 ``cacheable=False`` and ``auto_cache`` refuses it.
 
 Both wrap a small bidirectional JAX encoder over hash-tokenized text.
+``LLMScorer`` is a pointwise reranker over a DeepSeek-V2 decoder
+(``models/deepseek.py``), read at each pair's last real token.
 Execution details that matter on TPU/XLA:
 
 * miss batches run through ``BucketedRunner`` so the jitted scorer sees
@@ -34,10 +36,11 @@ from ..core import trace
 from ..core.frame import ColFrame
 from ..core.pipeline import Transformer, add_ranks
 from ..ir.tokenizer import HashTokenizer
+from . import deepseek
 from .common import ParamSpec, init_params, rms_norm
 
 __all__ = ["EncoderConfig", "encoder_param_specs", "encoder_score",
-           "MonoScorer", "DuoScorer"]
+           "MonoScorer", "DuoScorer", "LLMScorer"]
 
 
 @dataclass(frozen=True)
@@ -125,11 +128,32 @@ def _scoped_score(role: str, params: Dict, tokens: jnp.ndarray,
         return encoder_score(params, tokens, cfg)
 
 
-class _EncoderBase(Transformer):
-    #: the stage's role (``mono``, ``duo``), naming its tokenizing spans
-    #: and its programs' ops
+class _PairScorer(Transformer):
+    """What the (query, passage) scorers share: the tokenizing of a
+    call's pairs under the ``encoder.tokenize`` span."""
+
+    #: the stage's role (``mono``, ``duo``, ``llm``), naming its
+    #: tokenizing spans and its programs' ops
     role: str
 
+    def _tokenize(self, queries, texts) -> np.ndarray:
+        with trace.span("encoder.tokenize", role=self.role,
+                        pairs=len(queries)):
+            toks = self.tokenizer.encode_pairs(queries, texts,
+                                               self.cfg.max_len)
+        self.invocations += len(queries)
+        return toks
+
+    def _score_rows(self, inp: ColFrame) -> ColFrame:
+        """Each row's (query, text) pair scored on its own, then ranked."""
+        if len(inp) == 0:
+            return inp
+        scores = self._score_pairs(inp["query"].tolist(),
+                                   inp["text"].tolist())
+        return add_ranks(inp.assign(score=scores))
+
+
+class _EncoderBase(_PairScorer):
     def __init__(self, cfg: EncoderConfig, seed: int = 0):
         self.cfg = cfg
         self.seed = seed
@@ -150,12 +174,8 @@ class _EncoderBase(Transformer):
                                       max_bucket=1024)
 
     def _score_pairs(self, queries, texts) -> np.ndarray:
-        with trace.span("encoder.tokenize", role=self.role,
-                        pairs=len(queries)):
-            toks = self.tokenizer.encode_pairs(queries, texts,
-                                               self.cfg.max_len)
-        self.invocations += len(queries)
-        return np.asarray(self._runner(toks), dtype=np.float64)
+        return np.asarray(self._runner(self._tokenize(queries, texts)),
+                          dtype=np.float64)
 
 
 class MonoScorer(_EncoderBase):
@@ -169,11 +189,7 @@ class MonoScorer(_EncoderBase):
     cacheable = True
 
     def transform(self, inp: ColFrame) -> ColFrame:
-        if len(inp) == 0:
-            return inp
-        scores = self._score_pairs(inp["query"].tolist(),
-                                   inp["text"].tolist())
-        return add_ranks(inp.assign(score=scores))
+        return self._score_rows(inp)
 
     def signature(self):
         return ("MonoScorer", self.cfg.name, self.cfg.n_layers,
@@ -224,3 +240,71 @@ class DuoScorer(_EncoderBase):
     def signature(self):
         return ("DuoScorer", self.cfg.name, self.cfg.n_layers,
                 self.cfg.d_model, self.seed, self.max_docs)
+
+
+class LLMScorer(_PairScorer):
+    """Pointwise decoder-LM reranker (R→R): a pair's score is the ``yes``
+    logit less the ``no`` logit at its last real token
+    (``models/deepseek.py``).  Cache-safe like ``MonoScorer``.
+
+Its weights (``deepseek.param_specs``' layout) are given at
+    construction and are arguments of the jitted program
+    ``jit_llm_score``: scorers of one config share their compiled
+    buckets whatever their weights, nothing of the weights is compiled
+    in, and ``signature()`` names them by a digest of their bits.
+    Buckets hold
+    64 or 128 rows: one bucket's expert rows and attention scores must
+    fit beside a pipeline stage's weights on one chip.
+
+    Counters, while the profiler records: ``moe.rows``, the (token,
+    expert) rows of real tokens over the MoE layers, and ``moe.slots``,
+    the rows the dispatch computes (every slot of every bucket row).
+    """
+
+    role = "llm"
+
+    input_columns = frozenset({"qid", "query", "docno", "text"})
+    key_columns = ("query", "docno")
+    value_columns = ("score",)
+    cacheable = True
+
+    #: most rows of one call of the program
+    MAX_BUCKET = 128
+
+    def __init__(self, cfg: deepseek.DeepSeekConfig, params: Dict):
+        self.cfg = cfg
+        self.params = params
+        self._digest: Optional[str] = None
+        self.tokenizer = HashTokenizer(cfg.vocab_size)
+        self.invocations = 0
+        # (token, expert) rows per token over the MoE layers
+        self._routed = cfg.n_experts_per_tok * cfg.n_moe_layers
+        interpret = jax.default_backend() != "tpu"
+
+        def llm_score(params, tokens):
+            return deepseek.score(params, tokens, cfg, interpret=interpret)
+
+        def run(tokens):
+            trace.count("moe.slots", tokens.size * self._routed)
+            return default_compile_cache.call(
+                f"LLMScorer:{cfg.name}", llm_score, self.params, tokens)
+
+        self._runner = BucketedRunner(run, floor=DEVICE_BATCH_FLOOR,
+                                      max_bucket=self.MAX_BUCKET)
+
+    def _score_pairs(self, queries, texts) -> np.ndarray:
+        toks = self._tokenize(queries, texts)
+        trace.count("moe.rows",
+                    lambda: int(np.count_nonzero(toks)) * self._routed)
+        return np.asarray(self._runner(toks), dtype=np.float64)
+
+    def transform(self, inp: ColFrame) -> ColFrame:
+        return self._score_rows(inp)
+
+    def signature(self):
+        if self._digest is None:
+            self._digest = deepseek.digest(self.params)
+        c = self.cfg
+        return ("LLMScorer", c.name, c.n_layers, c.d_model,
+                c.n_routed_experts, c.max_len, str(jnp.dtype(c.dtype)),
+                self._digest)

@@ -10,6 +10,7 @@ module is imported — so every pytest-xdist worker collects the same
 tests and only the worker given this file loads the TPU library.
 """
 import functools
+import json
 import os
 
 import jax
@@ -21,7 +22,8 @@ from repro.ir.dense import _xla_chunk_topk
 from repro.kernels.bm25_block import bm25_block_op
 from repro.kernels.dense_topk import dense_topk_op
 from repro.kernels.flash_attention import flash_attention_op
-from repro.models.common import init_params
+from repro.models import deepseek
+from repro.models.common import abstract_params, init_params
 from repro.models.cross_encoder import (EncoderConfig, encoder_param_specs,
                                         encoder_score)
 from repro.serve.registry import _mono_config
@@ -103,3 +105,26 @@ def test_bm25_block_compiles(one_chip):
     compiled = _compile(functools.partial(bm25_block_op, avg_dl=55.0,
                                           interpret=False), tf, idf, doc_len)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_llm_scorer_compiles_at_dsv2_lite_widths(one_chip):
+    """DeepSeek-V2-Lite's first pipeline stage (7 of 27 layers, every
+    expert held) at the scorer's largest bucket, 128 rows x 256 tokens:
+    7.6 GB of bf16 weights as arguments, the grouped expert matmuls as
+    Mosaic kernels, and the whole program within the chip's 16 GB."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "configs",
+        "msmarco-bm25-dsv2lite.json")
+    with open(path) as f:
+        c = json.load(f)
+    cfg = deepseek.DeepSeekConfig.from_hf(c, name=c["name"],
+                                          max_len=c["max_len"])
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          abstract_params(deepseek.param_specs(cfg)))
+    tokens = _sds((128, cfg.max_len), jnp.int32, one_chip)
+    compiled = _compile(lambda p, t: deepseek.score(p, t, cfg),
+                        params, tokens)
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 7.5e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 14e9
