@@ -55,22 +55,11 @@ class CacheStats:
     #: identical with prefetch on or off, and ``prefetched`` only says
     #: how many round trips left the critical path.
     prefetched: int = 0
-    #: wall seconds spent inside the *wrapped transformer* on the miss
-    #: path, and the input queries those computes covered.  This is the
-    #: raw recompute cost — cache lookups/inserts excluded — which is
-    #: what the planner's cost model (core/cost.py) needs: the wrapper
-    #: call time a run records for a cached node is dominated by store
-    #: round trips, so folding it would make every cached node look
-    #: exactly as expensive as its cache and the cache-place pass could
-    #: never learn that recompute is cheaper.
-    compute_s: float = 0.0
-    compute_queries: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
 
     def add(self, *, hits: int = 0, misses: int = 0, inserts: int = 0,
-            verified: int = 0, prefetched: int = 0, compute_s: float = 0.0,
-            compute_queries: int = 0) -> None:
+            verified: int = 0, prefetched: int = 0) -> None:
         """Atomic increment — cache families are shared by the
         concurrent plan executor, so counter updates must not race."""
         with self._lock:
@@ -79,8 +68,6 @@ class CacheStats:
             self.inserts += inserts
             self.verified += verified
             self.prefetched += prefetched
-            self.compute_s += compute_s
-            self.compute_queries += compute_queries
 
     @property
     def lookups(self) -> int:
@@ -98,7 +85,7 @@ class CacheStats:
 def n_frame_queries(frame: Any) -> int:
     """How many input *queries* a frame covers: unique qids when the
     column exists, else rows.  Per-query is the planner cost model's
-    unit, so the families normalize ``CacheStats.compute_s`` by this."""
+    unit, so the families' miss-path compute is counted in it."""
     try:
         if "qid" in frame:
             return len(set(frame["qid"].tolist()))
@@ -418,6 +405,25 @@ class CacheTransformer(Transformer):
         counts = getattr(self._call_tls, "counts", (0, 0))
         self._call_tls.counts = (0, 0)
         return counts
+
+    def _note_compute(self, seconds: float, queries: int) -> None:
+        """Seconds spent inside the *wrapped transformer* on the miss
+        path, and the input queries that compute covered, tallied for
+        this thread's call.  This is the raw recompute cost — cache
+        lookups and inserts excluded — which the planner's cost model
+        (core/cost.py) needs: the wrapper call time a run records for a
+        cached node is dominated by store round trips.  The executor
+        pops it per node call (``pop_call_compute``), so nodes that
+        share one store each get their own misses' compute."""
+        prev = getattr(self._call_tls, "compute", (0.0, 0))
+        self._call_tls.compute = (prev[0] + seconds, prev[1] + int(queries))
+
+    def pop_call_compute(self) -> Tuple[float, int]:
+        """(seconds, queries) of miss-path compute accumulated by this
+        thread's calls since the last pop; resets to (0.0, 0)."""
+        compute = getattr(self._call_tls, "compute", (0.0, 0))
+        self._call_tls.compute = (0.0, 0)
+        return compute
 
     def call_with_counts(self, inp: Any) -> Tuple[Any, int, int]:
         """Run the cache and return ``(output, hits, misses)`` for THIS

@@ -213,8 +213,8 @@ class KeyValueCache(CacheTransformer):
             miss_frame = inp.take(np.asarray(rep_rows, dtype=np.int64))
             t0 = time.perf_counter()
             out = t(miss_frame)
-            self.stats.add(compute_s=time.perf_counter() - t0,
-                           compute_queries=n_frame_queries(miss_frame))
+            self._note_compute(time.perf_counter() - t0,
+                               n_frame_queries(miss_frame))
             if len(out) != len(rep_rows):
                 raise ValueError(
                     f"{type(self).__name__}: wrapped transformer returned "

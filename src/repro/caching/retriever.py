@@ -194,8 +194,8 @@ class RetrieverCache(CacheTransformer):
             sub = inp.take(np.asarray(still, dtype=np.int64))
             t0 = time.perf_counter()
             out = t(sub)
-            self.stats.add(compute_s=time.perf_counter() - t0,
-                           compute_queries=n_frame_queries(sub))
+            self._note_compute(time.perf_counter() - t0,
+                               n_frame_queries(sub))
             groups = out.group_indices(list(self.key_cols)) if len(out) else {}
             empty = out.take(np.asarray([], dtype=np.int64))
             items = []

@@ -459,12 +459,14 @@ def cmd_verify(args) -> int:
                             f"node {node.get('label')!r} references missing "
                             f"dir {nd!r} (gc'd or never populated)")
                     continue
-                if m.fingerprint and node.get("fingerprint") \
-                        and m.fingerprint != node["fingerprint"]:
+                # a shared store records its share key; older plan
+                # manifests carry only the node's own fingerprint
+                want = node.get("store_fingerprint") \
+                    or node.get("fingerprint")
+                if m.fingerprint and want and m.fingerprint != want:
                     problems.append(
                         f"node {node.get('label')!r}: plan fingerprint "
-                        f"{node['fingerprint']} != dir manifest "
-                        f"{m.fingerprint}")
+                        f"{want} != dir manifest {m.fingerprint}")
         report.append({"dir": name, "problems": problems})
 
     failed = [r for r in report if r["problems"]]

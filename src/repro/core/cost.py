@@ -42,6 +42,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from .ir import IRNode, PlanGraph
 
 __all__ = ["CostModel", "CostContext", "compute_node_fingerprints",
+           "stage_fingerprint",
            "fold_costs", "annotate_node_actuals", "analytic_stage_cost",
            "should_prefetch", "PREFETCH_MIN_ROUND_TRIP_S",
            "EWMA_ALPHA", "DEFAULT_STAGE_COST_S", "DEFAULT_COMBINE_COST_S"]
@@ -63,6 +64,15 @@ def _round_cost(x: float) -> float:
     return round(float(x), COST_DECIMALS)
 
 
+def stage_fingerprint(stage: Any) -> str:
+    """A stage's own provenance fingerprint: ``fingerprint()`` where
+    derivable, else a digest of its repr."""
+    from ..caching.auto import derive_fingerprint
+    from ..caching.provenance import combine_fingerprints
+    return derive_fingerprint(stage) \
+        or combine_fingerprints("sig", repr(stage))
+
+
 def compute_node_fingerprints(graph: PlanGraph) -> Dict[int, str]:
     """Provenance fingerprint per node (id-keyed): the stage fingerprint
     folded over the input nodes' fingerprints, bottom-up.
@@ -74,7 +84,6 @@ def compute_node_fingerprints(graph: PlanGraph) -> Dict[int, str]:
     provenance) stable under the one rewrite that is allowed to change
     physical operand order without changing results.
     """
-    from ..caching.auto import derive_fingerprint
     from ..caching.provenance import combine_fingerprints
     fps: Dict[int, str] = {
         graph.source.id: combine_fingerprints("plan-source")}
@@ -93,8 +102,7 @@ def compute_node_fingerprints(graph: PlanGraph) -> Dict[int, str]:
                                             type(node.stage).__name__)
             in_fps = sorted(in_fps)
         else:
-            stage_fp = derive_fingerprint(node.stage) \
-                or combine_fingerprints("sig", repr(node.stage))
+            stage_fp = stage_fingerprint(node.stage)
         fps[node.id] = combine_fingerprints(
             "node", node.kind, stage_fp, *in_fps)
     return fps

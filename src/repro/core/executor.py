@@ -168,6 +168,9 @@ class _NullRecorder(_Recorder):
 
 _NULL_RECORDER = _NullRecorder()
 
+#: guards IRNode.compute_s / compute_queries (see _timed_exec)
+_COMPUTE_LOCK = threading.Lock()
+
 
 def _effective_inputs(node: IRNode) -> List[IRNode]:
     """The inputs a scheduler must wait for.  Cache-prune: a probing
@@ -218,9 +221,17 @@ def _timed_exec(node: IRNode, ins: List[ColFrame],
                 extra_s: float = 0.0) -> ColFrame:
     """Run one node inside a ``plan.node`` span, and give ``rec`` the
     span's own clock readings (less ``extra_s`` at the start)."""
+    cache = node.cache if hasattr(node.cache, "pop_call_compute") else None
+    if cache is not None:
+        cache.pop_call_compute()         # drop stale counts on this thread
     with trace.timed("plan.node", node=node.label) as t:
         out = _exec_node(node, ins, batch_size)
     rec.add(node.label, shard, t.t0 - extra_s, t.t1)
+    if cache is not None:
+        seconds, queries = cache.pop_call_compute()
+        with _COMPUTE_LOCK:              # one node may run on many shards
+            node.compute_s += seconds
+            node.compute_queries += queries
     return out
 
 

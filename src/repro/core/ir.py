@@ -85,6 +85,11 @@ class IRNode:
     sched_priority: float = 0.0          # critical-path rank (operand-order)
     cache_skip: bool = False             # cache-place: cheaper to recompute
     backend_override: Optional[str] = None   # cache-place: hot-node promotion
+    #: cumulative miss-path compute of this node's cache calls (seconds,
+    #: queries covered) — the executor adds each call's, so nodes that
+    #: share one store keep their own figures for the cost model
+    compute_s: float = 0.0
+    compute_queries: int = 0
     # -- asynchronous data plane (caching/dataplane.py) ---------------------
     #: plan-stamped: executors issue this node's cache reads on the I/O
     #: pool as soon as the feeding frame exists (False for graphs built

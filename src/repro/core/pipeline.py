@@ -162,7 +162,10 @@ def add_ranks(res: ColFrame) -> ColFrame:
 class Transformer:
     """Base class for all pipeline stages."""
 
-    #: required input / produced output columns (None = unconstrained)
+    #: required input / produced output columns (None = unconstrained).
+    #: A stage that declares its inputs declares what it writes, in
+    #: ``value_columns`` or ``output_columns``: the planner takes every
+    #: other column as passed through (``core/plan.py`` ``share_keys``)
     input_columns: Optional[frozenset] = None
     output_columns: Optional[frozenset] = None
     #: cache-strategy metadata (beyond-paper §6 future work)

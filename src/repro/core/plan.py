@@ -50,7 +50,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
 
 from .cost import (CostContext, CostModel, annotate_node_actuals,
                    compute_node_fingerprints, fold_costs, should_prefetch,
-                   _round_cost)
+                   stage_fingerprint, _round_cost)
 from .executor import (_Recorder, resolve_n_shards, run_concurrent,
                        run_sequential, run_warm)
 from .frame import ColFrame
@@ -121,6 +121,74 @@ class PlanStats(PrecomputeStats):
                 f"saved={self.stage_invocations_saved} "
                 f"cache_hits={self.cache_hits} "
                 f"wall={self.wall_time_s:.3f}s{opt}{extra})")
+
+
+def _row_level(stage: Any) -> bool:
+    """Whether each output row of ``stage``, a stage that declares its
+    input columns, comes from its own input row alone: it is not
+    one-to-many, is cacheable, and its cache key names the document
+    (``docno``) — a pointwise scorer, a text loader.  Only such stages'
+    caches are shared across plan positions."""
+    return (not getattr(stage, "one_to_many", False)
+            and getattr(stage, "cacheable", True)
+            and "docno" in (getattr(stage, "key_columns", ()) or ()))
+
+
+def _declared_writes(node: IRNode) -> Optional[set]:
+    """The columns a stage node writes, by its declarations: its value
+    columns and the output columns it does not read, and ``rank`` where
+    it writes ``score`` (scorers re-rank).  Every other column passes
+    through it unchanged, or not at all (a cutoff writes none).  ``None``
+    when it may write any column: a combine or scale node, or a stage
+    that does not declare its input columns."""
+    stage = node.stage
+    inputs = getattr(stage, "input_columns", None)
+    if node.kind != "stage" or inputs is None:
+        return None
+    writes = set(getattr(stage, "value_columns", ()) or ())
+    outputs = getattr(stage, "output_columns", None)
+    if outputs is not None:
+        writes |= set(outputs) - set(inputs)
+    if "score" in writes:
+        writes.add("rank")
+    return writes
+
+
+def share_keys(graph: PlanGraph, fps: Dict[int, str]) -> Dict[int, str]:
+    """Store key per row-level stage node (:func:`_row_level`): the
+    stage's fingerprint folded with the provenance of each input column
+    it reads outside its key columns.  A column's provenance is the key
+    of the nearest upstream node that writes it — its store key if it is
+    row-level, else its node fingerprint (``fps``) — or the plan source.
+
+    Which rows an upstream cutoff or retriever selects does not enter
+    the key: a row-level stage's value for a row depends on that row
+    alone.  So one scorer under ``bm25 % 20`` and ``bm25 % 50`` gets one
+    key, and one store, while the same scorer after a different text
+    loader (other ``text`` for the same ``docno``) gets another."""
+    from ..caching.provenance import combine_fingerprints
+    #: node id -> (provenance of columns no declared writer touched,
+    #: {column: provenance of its declared writer})
+    prov: Dict[int, Tuple[str, Dict[str, str]]] = {
+        graph.source.id: (fps[graph.source.id], {})}
+    keys: Dict[int, str] = {}
+    for node in graph.nodes:
+        if node.kind == "source":
+            continue
+        writes = _declared_writes(node)
+        if writes is None:
+            prov[node.id] = (fps[node.id], {})
+            continue
+        default, cols = prov[node.inputs[0].id]
+        own = fps[node.id]
+        if _row_level(node.stage):
+            reads = sorted(set(node.stage.input_columns)
+                           - set(node.stage.key_columns))
+            own = keys[node.id] = combine_fingerprints(
+                "store", stage_fingerprint(node.stage),
+                *[(c, cols.get(c, default)) for c in reads])
+        prov[node.id] = (default, {**cols, **dict.fromkeys(writes, own)})
+    return keys
 
 
 def _accepted_kwargs(factory: Callable[..., Any],
@@ -283,7 +351,8 @@ class ExecutionPlan:
     def _label_nodes(self) -> None:
         """Unique display labels: the same stage planned under two
         different prefixes is two nodes and must not share a
-        ``node_times_s`` entry."""
+        ``node_times_s`` entry (even where the two share one cache
+        store, see :func:`share_keys`)."""
         seen: Dict[str, int] = {}
         for node in self.graph.nodes:
             if node.kind == "source":
@@ -378,26 +447,40 @@ class ExecutionPlan:
         if self.cache_budget is not None:
             kwargs["budget"] = self.cache_budget
         fps = self.node_fingerprints()
-        for node in self.graph.nodes:
-            if node.kind != "stage":
+        nodes = [n for n in self.graph.nodes
+                 if n.kind == "stage" and not n.cache_skip]  # cache-place
+        shared = share_keys(self.graph, fps)
+        # a store that cache-place promotes for one of its nodes is
+        # promoted for all of them
+        promoted: Dict[str, str] = {}
+        for node in nodes:
+            if node.id in shared and node.backend_override is not None:
+                promoted.setdefault(shared[node.id], node.backend_override)
+        opened: Dict[str, Any] = {}
+        for node in nodes:
+            store = shared.get(node.id)
+            if store in opened:
+                # one instance per store: the write-behind overlay makes
+                # one node's misses visible to the next node's lookup
+                node.cache = opened[store]
                 continue
-            if node.cache_skip:
-                continue                 # cache-place: recompute is cheaper
             path = None
             if self.cache_dir is not None:
-                # key the store by the node's full structural position so
-                # the same stage under different prefixes never collides;
-                # sha256 (not hash()) so the path is stable across
-                # processes; the commutative-canonical key (when the
-                # normalize pass ran) so it is stable under operand-order
-                # swaps — a reorder must never cool a warm cache
-                basis = node.canon_key if node.canon_key is not None \
+                # a row-level stage's store is keyed by its share key;
+                # any other's by the node's full structural position, so
+                # the same stage under different prefixes never
+                # collides — the commutative-canonical key (when the
+                # normalize pass ran), stable under operand-order swaps:
+                # a reorder must never cool a warm cache.  sha256 (not
+                # hash()) so the path is stable across processes
+                basis = store if store is not None else \
+                    node.canon_key if node.canon_key is not None \
                     else node.key
                 digest = hashlib.sha256(
                     repr(basis).encode()).hexdigest()[:16]
                 path = os.path.join(
                     self.cache_dir, pipeline_hash(node.stage) + "-" + digest)
-            wanted = {**kwargs, "fingerprint": fps[node.id],
+            wanted = {**kwargs, "fingerprint": store or fps[node.id],
                       "on_stale": self.on_stale,
                       # planner-inserted caches opt into write-behind:
                       # the plan's close()/collect path drains them, and
@@ -406,11 +489,22 @@ class ExecutionPlan:
                       # deterministic transformers (hand-wrapped caches
                       # keep synchronous puts unless asked)
                       "async_writes": True}
-            if node.backend_override is not None:
-                wanted["backend"] = node.backend_override
+            override = promoted.get(store, node.backend_override)
+            if override is not None:
+                wanted["backend"] = override
             node.cache = factory(node.stage, path,
                                  **_accepted_kwargs(factory, wanted))
+            if store is not None:
+                opened[store] = node.cache
         self._stamp_prefetch()
+
+    def _caches(self) -> List[Any]:
+        """The planner-inserted caches, each instance once."""
+        seen: Dict[int, Any] = {}
+        for node in self.graph.nodes:
+            if node.cache is not None:
+                seen.setdefault(id(node.cache), node.cache)
+        return list(seen.values())
 
     def _stamp_prefetch(self) -> None:
         """Mark which memoized nodes the executors should prefetch:
@@ -459,6 +553,10 @@ class ExecutionPlan:
                 "fingerprint": fps[node.id],
                 "dir": os.path.basename(cache_path)
                        if cache_path is not None else None,
+                # what the store was opened with: the share key of a
+                # row-level node's store, else the node's fingerprint
+                "store_fingerprint": getattr(
+                    cache, "provenance_fingerprint", None),
                 "family": type(cache).__name__ if cache is not None else None,
                 "inputs": [i.id for i in node.inputs],
                 "touched_by": list(node.touched_by),
@@ -607,18 +705,18 @@ class ExecutionPlan:
     def close(self) -> None:
         """Close planner-inserted caches (flushes temporary stores and
         write-behind queues)."""
-        for node in self.graph.nodes:
-            if node.cache is not None and hasattr(node.cache, "close"):
-                node.cache.close()
+        for cache in self._caches():
+            if hasattr(cache, "close"):
+                cache.close()
 
     def drain(self) -> None:
         """Make planner-inserted caches durable without closing them:
         flush each family's write-behind queue and access log
         (``caching/dataplane.py``).  A crash after ``drain()`` returns
         loses nothing; a crash before it recomputes pending entries."""
-        for node in self.graph.nodes:
-            if node.cache is not None and hasattr(node.cache, "drain"):
-                node.cache.drain()
+        for cache in self._caches():
+            if hasattr(cache, "drain"):
+                cache.drain()
 
     def __enter__(self) -> "ExecutionPlan":
         return self
@@ -768,8 +866,8 @@ class ExecutionPlan:
 
     def _cache_counters(self) -> Tuple[int, int, int]:
         hits = misses = prefetched = 0
-        for node in self.graph.nodes:
-            cs = getattr(node.cache, "stats", None)
+        for cache in self._caches():     # a shared store counts once
+            cs = getattr(cache, "stats", None)
             if cs is not None:
                 hits += cs.hits
                 misses += cs.misses
@@ -778,14 +876,12 @@ class ExecutionPlan:
 
     def _compute_counters(self) -> Dict[str, Tuple[float, int]]:
         """Cumulative raw-compute counters per *cached* node label (see
-        ``CacheStats.compute_s``) — snapshot before a run, delta after."""
-        out: Dict[str, Tuple[float, int]] = {}
-        for node in self.graph.nodes:
-            cs = getattr(node.cache, "stats", None)
-            if cs is not None and node.label is not None:
-                out[node.label] = (float(getattr(cs, "compute_s", 0.0)),
-                                   int(getattr(cs, "compute_queries", 0)))
-        return out
+        ``CacheTransformer._note_compute``) — snapshot before a run,
+        delta after."""
+        return {node.label: (node.compute_s, node.compute_queries)
+                for node in self.graph.nodes
+                if getattr(node.cache, "stats", None) is not None
+                and node.label is not None}
 
     def _fill_compute_stats(self, stats: PlanStats,
                             base: Dict[str, Tuple[float, int]]) -> None:

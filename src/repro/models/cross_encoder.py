@@ -203,6 +203,7 @@ class DuoScorer(_EncoderBase):
     role = "duo"
 
     input_columns = frozenset({"qid", "query", "docno", "text"})
+    value_columns = ("score",)
     cacheable = False
 
     def __init__(self, cfg: EncoderConfig, seed: int = 1, max_docs: int = 10):

@@ -546,3 +546,205 @@ def test_concurrent_processes_share_plan_cache_dir(tmp_path, backend):
     assert sorted(computed) == sorted(f"q{i}" for i in range(6)), \
         f"{backend}: entries computed more than once: {computed}"
 
+
+
+# ---------------------------------------------------------------------------
+# shared stores: one cache per row-level stage across a grid's depths
+# ---------------------------------------------------------------------------
+
+def _assert_bitwise(got_frames, want_frames):
+    cols = ["qid", "query", "docno", "text", "score", "rank"]
+    for got, want in zip(got_frames, want_frames):
+        assert got.sort_values(SORT).equals(want.sort_values(SORT),
+                                            cols=cols, rtol=0, atol=0)
+
+
+def _stage_caches(plan, stage):
+    return [n.cache for n in plan.graph.nodes
+            if n.kind == "stage" and n.stage is stage]
+
+
+@pytest.mark.parametrize("run_kw", [{}, {"n_shards": 3, "max_workers": 8}],
+                         ids=["sequential", "sharded"])
+def test_pointwise_scorer_shares_one_store_across_depths(tmp_path, run_kw):
+    """``retr % k >> loader >> scorer`` for k in 2, 5, 10: the scorer's
+    and the loader's nodes share one cache each, so every (query,
+    docno) is scored once, and results are those of an uncached plan —
+    also with the depths' nodes racing on the shared stores."""
+    import sys
+    from _depth_grid import DEPTHS, QUERIES, PairScorer, grid
+    want, _ = ExecutionPlan(grid(PairScorer())).run(QUERIES)
+    scorer = PairScorer()
+    pipelines = grid(scorer)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)          # interleave the racing threads
+    try:
+        with ExecutionPlan(pipelines, cache_dir=str(tmp_path)) as plan:
+            caches = _stage_caches(plan, scorer)
+            assert len(caches) == len(DEPTHS)
+            assert all(c is caches[0] for c in caches)
+            loader = pipelines[0].stages[1]
+            assert len({id(c) for c in _stage_caches(plan, loader)}) == 1
+            outs, stats = plan.run(QUERIES, **run_kw)
+            labels = [n.label for n in plan.graph.nodes
+                      if n.stage is scorer]
+            computed = [stats.node_compute_queries[label]
+                        for label in labels]
+            _, again = plan.run(QUERIES, **run_kw)
+    finally:
+        sys.setswitchinterval(interval)
+    n_q, top = len(QUERIES), max(DEPTHS)
+    assert len(scorer.pairs) == len(set(scorer.pairs)) == n_q * top
+    _assert_bitwise(outs, want)
+    # rows looked up by the loader and by the scorer: sum of the depths
+    # per query, of which each distinct passage misses once; the one
+    # retriever node the depths share misses once per query
+    rows = n_q * sum(DEPTHS)
+    assert stats.cache_misses == 2 * n_q * top + n_q
+    assert stats.cache_hits == 2 * (rows - n_q * top)
+    # each scorer node keeps its own misses' compute: in order, every
+    # query had passages the earlier depths had not scored; racing, the
+    # nodes split the queries that computed anything
+    if not run_kw:
+        assert computed == [n_q] * len(DEPTHS)
+    assert n_q <= sum(computed) <= n_q * len(DEPTHS)
+    assert again.cache_misses == 0
+    assert again.cache_hits == 2 * rows + n_q
+    assert len(scorer.pairs) == n_q * top
+
+
+_SHARED_STORE_SCRIPT = """
+import sys
+sys.path.insert(0, sys.argv[2])
+from _depth_grid import QUERIES, PairScorer, grid
+from repro.core import ExecutionPlan, OPTIMIZER_PASSES
+scorer = PairScorer()
+# cache-place left out: these toy stages recompute about as fast as a
+# store round trip, so it could drop a store the test means to reopen
+passes = [p for p in OPTIMIZER_PASSES if p != "cache-place"]
+with ExecutionPlan(grid(scorer), cache_dir=sys.argv[1],
+                   on_stale="error", optimize=passes) as plan:
+    outs, stats = plan.run(QUERIES)
+print(stats.cache_hits, stats.cache_misses, len(scorer.pairs))
+for out in outs:
+    print(out.sort_values(["qid", "docno"])["score"].tobytes().hex())
+"""
+
+
+def test_shared_store_is_warm_in_a_fresh_process(tmp_path):
+    """A second interpreter planning the same grid over the same
+    directory opens every shared store cleanly (``on_stale="error"``)
+    and hits everything, with the first process's scores."""
+    import os
+    import subprocess
+    import sys
+    from _depth_grid import DEPTHS, QUERIES
+    from repro.cli import main
+    tests = os.path.dirname(os.path.abspath(__file__))
+    env = {**os.environ,
+           "PYTHONPATH": os.path.join(os.path.dirname(tests), "src")}
+    runs = []
+    for _ in range(2):
+        p = subprocess.run([sys.executable, "-c", _SHARED_STORE_SCRIPT,
+                            str(tmp_path), tests],
+                           capture_output=True, text=True, env=env,
+                           timeout=120)
+        assert p.returncode == 0, p.stderr[-2000:]
+        runs.append(p.stdout.split())
+    n_q, rows = len(QUERIES), len(QUERIES) * sum(DEPTHS)
+    assert runs[0][2] == str(n_q * max(DEPTHS))  # pairs scored, cold
+    assert runs[1][:3] == [str(2 * rows + n_q), "0", "0"]
+    assert runs[1][3:] == runs[0][3:]
+    assert main(["cache", "verify", str(tmp_path)]) == 0
+
+
+def test_scorer_after_another_text_loader_keeps_its_own_store(tmp_path):
+    """The same scorer after two text loaders that give one docno other
+    texts must not share a store: the text is part of its input."""
+    from _depth_grid import QUERIES, PairScorer, Retriever, loader
+    retr = Retriever()
+    pipelines = [retr >> loader("v1") >> PairScorer(),
+                 retr >> loader("v2") >> PairScorer()]
+    want = [p(QUERIES) for p in pipelines]
+    scorer = PairScorer()
+    pipelines = [retr >> loader("v1") >> scorer,
+                 retr >> loader("v2") >> scorer]
+    with ExecutionPlan(pipelines, cache_dir=str(tmp_path)) as plan:
+        a, b = _stage_caches(plan, scorer)
+        assert a is not b and a.path != b.path
+        outs, _ = plan.run(QUERIES)
+    _assert_bitwise(outs, want)
+    assert not outs[0].equals(outs[1], cols=["score"])
+    assert len(scorer.pairs) == 2 * len(set(scorer.pairs))
+
+
+def _retriever_after_rewriters():
+    """One-to-many: a retriever under two query rewriters."""
+    from _depth_grid import Retriever
+    from repro.ir import QueryExpander
+    retr = Retriever()
+    return retr, [QueryExpander(2) >> retr, QueryExpander(3) >> retr]
+
+
+def _one_to_many_by_docno_at_depths():
+    """One-to-many though keyed by docno: each passage in two parts."""
+    from _depth_grid import Retriever
+
+    def fn(inp):
+        out = inp.take(np.repeat(np.arange(len(inp)), 2))
+        return out.assign(part=np.tile([0.0, 1.0], len(inp)))
+    stage = GenericTransformer(fn, "split", key_columns=("docno",),
+                               value_columns=("part",), one_to_many=True)
+    stage.input_columns = frozenset({"qid", "docno"})
+    retr = Retriever()
+    return stage, [retr % 2 >> stage, retr % 5 >> stage]
+
+
+def _qid_keyed_at_depths():
+    """Row-wise, but keyed by qid: each row gets its query's length."""
+    from _depth_grid import Retriever
+
+    def fn(inp):
+        return inp.assign(qlen=np.asarray(
+            [len(q) for q in inp["query"].tolist()], dtype=np.float64))
+    stage = GenericTransformer(fn, "qlen", key_columns=("qid",),
+                               value_columns=("qlen",))
+    stage.input_columns = frozenset({"qid", "query"})
+    retr = Retriever()
+    return stage, [retr % 2 >> stage, retr % 5 >> stage]
+
+
+def _undeclared_inputs_at_depths():
+    """Keyed by docno, but declares no input columns."""
+    from _depth_grid import Retriever
+
+    def fn(inp):
+        return inp.assign(tag=np.asarray(
+            [d.upper() for d in inp["docno"].tolist()], dtype=object))
+    stage = GenericTransformer(fn, "tag", key_columns=("docno",),
+                               value_columns=("tag",))
+    retr = Retriever()
+    return stage, [retr % 2 >> stage, retr % 5 >> stage]
+
+
+@pytest.mark.parametrize("make", [
+    _retriever_after_rewriters, _one_to_many_by_docno_at_depths,
+    _qid_keyed_at_depths, _undeclared_inputs_at_depths,
+], ids=["retriever", "one_to_many_by_docno", "qid_keyed",
+        "undeclared_inputs"])
+def test_non_row_level_caches_keep_per_position_stores(tmp_path, make):
+    """Only row-level stages share: a retriever, any one-to-many stage,
+    a qid-keyed stage and a stage that does not declare its inputs each
+    keep one store per plan position, and give the results of an
+    uncached run."""
+    from _depth_grid import QUERIES
+    stage, pipelines = make()
+    want = [p(QUERIES) for p in pipelines]
+    with ExecutionPlan(pipelines, cache_dir=str(tmp_path)) as plan:
+        a, b = _stage_caches(plan, stage)
+        assert a is not None and b is not None
+        assert a is not b and a.path != b.path
+        outs, _ = plan.run(QUERIES)
+    for got, w in zip(outs, want):
+        assert got.sort_values(SORT).equals(
+            w.sort_values(SORT), cols=list(w.columns), rtol=0, atol=0)
