@@ -1,6 +1,9 @@
 """Planner benchmarks: vectorized hot paths, plan-vs-naive sharing,
 the optimizer pass pipeline, the concurrent sharded executor, and the
-cost-aware optimizer (``--suite bench_optimizer_cost``).
+asynchronous cache data plane (``--suite dataplane``).
+
+These run on the CPU: their counts and checksums are checks, their
+times are not evidence of speed on the chip (``bench/`` measures that).
 
 Five suites:
 
@@ -20,20 +23,9 @@ Five suites:
    I/O / BLAS / accelerator dispatch that dominates real pipelines).
    The acceptance bar is ≥1.5× with ≥4 workers (≥1.0× in ``--quick``
    CI smoke mode, where runner timing is noisy).
-5. Cost-aware optimizer (``--suite bench_optimizer_cost``, needs
-   ``--cache-dir``): a 3-pipeline hybrid workload compiled twice per
-   invocation — a *static* leg (the cost-blind pass list, default
-   knobs) and a *tuned* leg (``optimize="all"``, executor knobs from
-   ``plan.tuning()``) — over two sub-directories of one cache dir.
-   The first invocation runs on cold analytic/default priors; a second
-   invocation over the same dir compiles against the measured costs
-   the first folded into the plan manifests, and asserts the
-   self-tuned leg beats the static leg on wall time, that cache-place
-   dropped the memo of a provably cheap node (manifest ``dir: null``)
-   while never touching the expensive ones, and that every leg and
-   run produces the same ``result_checksum``.  Always writes
-   ``BENCH_optimizer.json`` next to the CWD so the perf trajectory is
-   tracked across PRs.
+5. Asynchronous cache data plane (``--suite dataplane``, needs
+   ``--cache-dir``): prefetch on and off over one warm directory behind
+   a simulated remote store, with equal result checksums.
 
 ``--quick`` shrinks the workloads for the CI smoke job; ``--json PATH``
 dumps every row plus the concurrent run's ``PlanStats`` and the
@@ -317,156 +309,7 @@ def bench_concurrent_executor(quick: bool = False,
     return row
 
 
-# -- cost-aware optimizer: static pass list vs self-tuned -------------------
-
-#: the cost-blind baseline the tuned leg is compared against — the full
-#: structural pipeline minus the three cost-aware passes
-STATIC_PASSES = ["normalize", "cse", "pushdown", "cache-prune"]
-
-
-def _tag_stage():
-    """A provably cheap cacheable stage: a pure vectorized column
-    assignment with declared key/value columns, so the planner inserts
-    a KeyValueCache around it — until measured history shows recompute
-    is cheaper than the backend round trip and cache-place drops it."""
-    def fn(inp):
-        return inp.assign(tag=inp["docno"])  # pure column copy: ~1µs/query
-    return GenericTransformer(fn, "tag_join", key_columns=("qid", "docno"),
-                              value_columns=("tag",))
-
-
-def _cost_workload(quick: bool):
-    """Hybrid 2-pipeline workload mixing every cost regime: an
-    expensive and a cheap retriever under a commutative combine
-    (operand-order evidence), two uncached sleep-dominated rerankers
-    (autotune's sharding evidence), and a trivially cheap cached tag
-    stage (cache-place's skip evidence)."""
-    n_queries = 24 if quick else 48
-    per = 0.002 if quick else 0.004
-    topics = ColFrame({"qid": [f"q{i}" for i in range(n_queries)],
-                       "query": [f"terms {i}" for i in range(n_queries)]})
-    heavy = _simulated_stage("sim_heavy_retr", 3 * per, 100.0, n_docs=8)
-    light = _simulated_stage("sim_light_retr", per, 50.0, n_docs=5)
-    rerank_a = _simulated_stage("sim_rerankA", per, 1.0)
-    rerank_b = _simulated_stage("sim_rerankB", per, 2.0)
-    systems = [(light + heavy) % 5 >> _tag_stage() >> rerank_a,
-               heavy % 8 >> rerank_b]
-    return topics, systems
-
-
-#: explicit backend for the cost suite: pickle's per-entry round trip
-#: (~10µs here) sits comfortably ABOVE the tag stage's measured
-#: recompute (~2µs — skip window) and far BELOW the retrievers'
-#: (milliseconds — no false skip, and 20×-round-trip promotion fires)
-COST_SUITE_BACKEND = "pickle"
-
-
-def _run_cost_leg(topics, systems, cache_dir: str, tuned: bool) -> Dict:
-    """One compile+run over its own cache dir: the tuned leg plans with
-    ``optimize="all"`` and forwards the autotuned ``n_shards`` to the
-    executor; the static leg uses the cost-blind pass list and default
-    (sequential) knobs."""
-    optimize = "all" if tuned else STATIC_PASSES
-    with ExecutionPlan(systems, cache_dir=cache_dir,
-                       cache_backend=COST_SUITE_BACKEND,
-                       optimize=optimize) as plan:
-        shards = plan.tuning().get("n_shards") if tuned else None
-        outs, stats = plan.run(
-            topics,
-            n_shards=int(shards) if shards else None,
-            max_workers=int(shards) if shards else None)
-        record = plan.to_record()
-    return {"wall_s": stats.wall_time_s,
-            "n_shards": stats.n_shards,
-            "cache_hits": stats.cache_hits,
-            "cache_misses": stats.cache_misses,
-            "result_checksum": frame_checksum(outs),
-            "nodes": record["nodes"],
-            "optimizer": record["optimizer"],
-            "tuning": {k: v.get("value")
-                       for k, v in record.get("tuning", {}).items()}}
-
-
-def bench_optimizer_cost(cache_dir: str, quick: bool = False) -> Dict:
-    """Static vs self-tuned planning over one persistent cache dir.
-
-    Run this suite TWICE against the same ``--cache-dir`` (the CI
-    optimizer-smoke job does).  The first invocation compiles on cold
-    analytic/default priors — the cost-aware passes refuse to act on
-    weak evidence, so both legs run identically and the run's measured
-    per-node costs are folded into each leg's plan manifest.  The
-    second invocation compiles against that measured history and must
-    show: the tuned leg beating the static leg on wall time (autotuned
-    sharding overlaps the sleep-dominated rerankers), the cheap tag
-    stage's planner cache provably dropped (``cache_skip`` with
-    ``dir: null``) while the expensive retrievers — whose recompute
-    dwarfs the backend round trip — stay cached, the commutative
-    combine reordered expensive-subtree-first, and bit-identical
-    result checksums across every leg and phase.
-    """
-    topics, systems = _cost_workload(quick)
-    static = _run_cost_leg(topics, systems,
-                           os.path.join(cache_dir, "static"), tuned=False)
-    tuned = _run_cost_leg(topics, systems,
-                          os.path.join(cache_dir, "tuned"), tuned=True)
-    nodes = tuned["nodes"]
-    measured = any(n.get("cost_src") == "measured" for n in nodes)
-
-    assert tuned["result_checksum"] == static["result_checksum"], \
-        "cost-aware planning changed result bits"
-    tag_nodes = [n for n in nodes if "tag_join" in n["label"]
-                 and n["kind"] == "stage"]
-    retr_nodes = [n for n in nodes if n["kind"] == "stage"
-                  and ("sim_heavy_retr" in n["label"]
-                       or "sim_light_retr" in n["label"])]
-    assert tag_nodes and retr_nodes, "workload shape changed"
-    # expensive nodes must NEVER be skipped: their recompute cost dwarfs
-    # the cache round trip, in either phase
-    assert all(not n["cache_skip"] and n["dir"] is not None
-               for n in retr_nodes), \
-        f"cache-place dropped an expensive node's cache: {retr_nodes}"
-    if measured:
-        assert all(n["cache_skip"] and n["dir"] is None
-                   for n in tag_nodes), \
-            f"cache-place kept a cache cheaper to recompute: {tag_nodes}"
-        assert tuned["optimizer"]["inputs_reordered"] >= 1, \
-            "operand-order did not reorder the commutative combine"
-        # the hot retrievers cost 20×+ the round trip: promoted to a
-        # memory-tiered selector over the same store (tiered:pickle)
-        assert tuned["optimizer"]["caches_promoted"] >= 1, \
-            "cache-place promoted no hot node"
-        assert int(tuned["tuning"].get("n_shards") or 0) >= 2, \
-            f"autotune chose no sharding: {tuned['tuning']}"
-        assert tuned["n_shards"] >= 2
-        assert static["cache_hits"] > 0 and tuned["cache_hits"] > 0, \
-            "second invocation did not start warm"
-        assert tuned["wall_s"] < static["wall_s"], \
-            f"self-tuned plan not faster: tuned {tuned['wall_s']:.4f}s " \
-            f"vs static {static['wall_s']:.4f}s"
-    else:
-        # cold priors are weak evidence: no cache may be dropped on them
-        assert not any(n["cache_skip"] for n in nodes), \
-            f"cache-place skipped on cold priors: {nodes}"
-
-    return {"name": "optimizer_cost_static_vs_tuned",
-            "phase": "measured" if measured else "cold",
-            "t_static_s": round(static["wall_s"], 4),
-            "t_tuned_s": round(tuned["wall_s"], 4),
-            "speedup": round(static["wall_s"] / max(tuned["wall_s"], 1e-9),
-                             2),
-            "n_shards_tuned": tuned["n_shards"],
-            "tuning": tuned["tuning"],
-            "caches_skipped": tuned["optimizer"]["caches_skipped"],
-            "caches_promoted": tuned["optimizer"]["caches_promoted"],
-            "inputs_reordered": tuned["optimizer"]["inputs_reordered"],
-            "skipped_nodes": [n["label"] for n in nodes
-                              if n.get("cache_skip")],
-            "static_cache_hits": static["cache_hits"],
-            "tuned_cache_hits": tuned["cache_hits"],
-            "result_checksum": static["result_checksum"]}
-
-
-# -- suite 6: asynchronous cache data plane (--suite dataplane) --------------
+# -- suite 5: asynchronous cache data plane (--suite dataplane) --------------
 
 #: simulated remote-tier round trip per ``get_many`` call.  Local page-
 #: cache reads finish in microseconds — prefetch has nothing to hide
@@ -518,15 +361,11 @@ def _dataplane_workload(quick: bool):
 
 def _dataplane_leg(topics, systems, cache_dir: str, *, prefetch: bool,
                    repeats: int = 3) -> Dict:
-    """Best-of-N warm run over an already-populated dir.  The static
-    pass list keeps the cost-aware optimizer from re-planning the
-    caches between legs (this suite measures the data plane, not
-    cache placement)."""
+    """Best-of-N warm run over an already-populated dir."""
     best, stats, outs = float("inf"), None, None
     for _ in range(repeats):
         with ExecutionPlan(systems, cache_dir=cache_dir,
                            cache_backend="remote-sim",
-                           optimize=STATIC_PASSES,
                            prefetch=prefetch) as plan:
             t0 = time.perf_counter()
             outs, stats = plan.run(topics)
@@ -611,26 +450,14 @@ def main(argv: Optional[List[str]] = None):
                     help="run the concurrent suite against a persistent "
                          "planner cache dir (cold/warm cache-compat CI)")
     ap.add_argument("--suite",
-                    choices=["all", "bench_optimizer_cost", "dataplane"],
+                    choices=["all", "dataplane"],
                     default="all",
-                    help="'bench_optimizer_cost' runs only the cost-aware "
-                         "optimizer suite (requires --cache-dir; run it "
-                         "twice over one dir: cold priors, then measured); "
-                         "'dataplane' runs the async-data-plane suite "
+                    help="'dataplane' runs the async-data-plane suite "
                          "(prefetch on/off over one warm dir, requires "
                          "--cache-dir)")
     args = ap.parse_args(argv)
     optimize = "none" if args.no_optimize else "all"
-    if args.suite == "bench_optimizer_cost":
-        if args.cache_dir is None:
-            ap.error("--suite bench_optimizer_cost requires --cache-dir")
-        rows = [bench_optimizer_cost(args.cache_dir, quick=args.quick)]
-        # the perf-trajectory artifact CI tracks across PRs
-        with open("BENCH_optimizer.json", "w") as f:
-            json.dump({"suite": "bench_optimizer_cost", "rows": rows},
-                      f, indent=2)
-        print("[wrote BENCH_optimizer.json]")
-    elif args.suite == "dataplane":
+    if args.suite == "dataplane":
         if args.cache_dir is None:
             ap.error("--suite dataplane requires --cache-dir")
         rows = [bench_dataplane(args.cache_dir, quick=args.quick)]

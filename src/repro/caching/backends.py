@@ -56,7 +56,7 @@ __all__ = ["CacheBackend", "MemoryLRUBackend", "PickleDirBackend",
            "open_backend", "resolve_backend_name", "select_backend",
            "BACKENDS", "split_tiered", "split_mmap", "split_combinator",
            "registered_selectors", "storage_identity",
-           "backend_store_exists", "measure_round_trip"]
+           "backend_store_exists"]
 
 
 # ---------------------------------------------------------------------------
@@ -800,57 +800,6 @@ def open_backend(spec: Union[str, CacheBackend, None], path: Optional[str],
         from .mmap_tier import MmapTier         # deferred: imports us
         return MmapTier(path, disk=disk)
     return BACKENDS[name](path)
-
-
-# one measurement per resolved selector per process — the figure feeds
-# cost *estimates*, so amortizing it is more valuable than freshness
-_ROUND_TRIP_CACHE: Dict[str, float] = {}
-_ROUND_TRIP_LOCK = threading.Lock()
-
-
-def measure_round_trip(spec: Union[str, CacheBackend, None], *,
-                       default: str = "sqlite", payload_bytes: int = 2048,
-                       n_entries: int = 32, n_rounds: int = 3) -> float:
-    """Measured warm per-entry round-trip cost of a backend selector
-    (seconds): the amortized cost of one entry in a batched
-    ``get_many`` over a freshly-written throwaway store.
-
-    This is the figure the plan compiler's ``cache-place`` pass weighs
-    against a node's estimated recompute cost — caching a stage whose
-    recompute is cheaper than this round trip only *adds* latency (and
-    disk), so the planner skips it.  Microbenchmarked once per resolved
-    selector per process (cached); combinator selectors
-    (``tiered:<disk>`` / ``mmap:<disk>``) measure the combinator's own
-    warm-hit path, which is the one serving traffic sees.
-    """
-    name = resolve_backend_name(spec, default)
-    with _ROUND_TRIP_LOCK:
-        hit = _ROUND_TRIP_CACHE.get(name)
-    if hit is not None:
-        return hit
-    import shutil
-    import time
-    tmp = tempfile.mkdtemp(prefix="repro-rt-")
-    try:
-        backend = open_backend(name, tmp)
-        try:
-            payload = b"\x5a" * max(1, int(payload_bytes))
-            keys = [b"rt-%06d" % i for i in range(max(1, int(n_entries)))]
-            backend.put_many((k, payload) for k in keys)
-            backend.get_many(keys)       # warm any front tier / page cache
-            best = float("inf")
-            for _ in range(max(1, int(n_rounds))):
-                t0 = time.perf_counter()
-                backend.get_many(keys)
-                best = min(best, time.perf_counter() - t0)
-            per_entry = best / len(keys)
-        finally:
-            backend.close()
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    with _ROUND_TRIP_LOCK:
-        _ROUND_TRIP_CACHE[name] = per_entry
-    return per_entry
 
 
 def backend_store_exists(name: Optional[str], path: str) -> bool:

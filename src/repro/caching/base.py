@@ -31,7 +31,7 @@ from .economics import AccessStats, CacheBudget, evict_entries
 from .provenance import CacheManifest, ManifestError, StaleCacheError
 
 __all__ = ["CacheMissError", "CacheStats", "CacheTransformer",
-           "n_frame_queries", "resolve_transformer", "pickle_key",
+           "resolve_transformer", "pickle_key",
            "pickle_value", "unpickle_value"]
 
 #: valid ``on_stale=`` policies (see CacheTransformer)
@@ -80,18 +80,6 @@ class CacheStats:
     def __str__(self):
         return (f"hits={self.hits} misses={self.misses} "
                 f"hit_rate={self.hit_rate:.3f}")
-
-
-def n_frame_queries(frame: Any) -> int:
-    """How many input *queries* a frame covers: unique qids when the
-    column exists, else rows.  Per-query is the planner cost model's
-    unit, so the families' miss-path compute is counted in it."""
-    try:
-        if "qid" in frame:
-            return len(set(frame["qid"].tolist()))
-    except Exception:
-        pass
-    return len(frame)
 
 
 def resolve_transformer(t: Any) -> Optional[Transformer]:
@@ -405,25 +393,6 @@ class CacheTransformer(Transformer):
         counts = getattr(self._call_tls, "counts", (0, 0))
         self._call_tls.counts = (0, 0)
         return counts
-
-    def _note_compute(self, seconds: float, queries: int) -> None:
-        """Seconds spent inside the *wrapped transformer* on the miss
-        path, and the input queries that compute covered, tallied for
-        this thread's call.  This is the raw recompute cost — cache
-        lookups and inserts excluded — which the planner's cost model
-        (core/cost.py) needs: the wrapper call time a run records for a
-        cached node is dominated by store round trips.  The executor
-        pops it per node call (``pop_call_compute``), so nodes that
-        share one store each get their own misses' compute."""
-        prev = getattr(self._call_tls, "compute", (0.0, 0))
-        self._call_tls.compute = (prev[0] + seconds, prev[1] + int(queries))
-
-    def pop_call_compute(self) -> Tuple[float, int]:
-        """(seconds, queries) of miss-path compute accumulated by this
-        thread's calls since the last pop; resets to (0.0, 0)."""
-        compute = getattr(self._call_tls, "compute", (0.0, 0))
-        self._call_tls.compute = (0.0, 0)
-        return compute
 
     def call_with_counts(self, inp: Any) -> Tuple[Any, int, int]:
         """Run the cache and return ``(output, hits, misses)`` for THIS

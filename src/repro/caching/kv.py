@@ -13,15 +13,13 @@ entry exactly once.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
 
 from ..core.frame import ColFrame
 from .backends import CacheBackend, open_backend, resolve_backend_name
-from .base import (CacheTransformer, n_frame_queries, pickle_key,
-                   pickle_value, unpickle_value)
+from .base import CacheTransformer, pickle_key, pickle_value, unpickle_value
 from .codecs import (KV_CODEC, decode_kv_batch, decode_kv_value,
                      encode_kv_value, vector_keys)
 
@@ -211,10 +209,7 @@ class KeyValueCache(CacheTransformer):
                 uniq.setdefault(keys[i], []).append(i)
             rep_rows = [idxs[0] for idxs in uniq.values()]
             miss_frame = inp.take(np.asarray(rep_rows, dtype=np.int64))
-            t0 = time.perf_counter()
             out = t(miss_frame)
-            self._note_compute(time.perf_counter() - t0,
-                               n_frame_queries(miss_frame))
             if len(out) != len(rep_rows):
                 raise ValueError(
                     f"{type(self).__name__}: wrapped transformer returned "

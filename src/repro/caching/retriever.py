@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import hashlib
 import pickle
-import time
 import zlib
 from typing import Any, List, Optional, Tuple
 
@@ -31,7 +30,7 @@ import numpy as np
 
 from ..core.frame import ColFrame
 from .backends import CacheBackend, open_backend, resolve_backend_name
-from .base import CacheTransformer, n_frame_queries, pickle_key
+from .base import CacheTransformer, pickle_key
 from .codecs import (RETRIEVER_CODEC, decode_columnar_frame,
                      encode_columnar_frame, vector_keys)
 
@@ -192,10 +191,7 @@ class RetrieverCache(CacheTransformer):
                 return []
             t = self._require_transformer(len(still))
             sub = inp.take(np.asarray(still, dtype=np.int64))
-            t0 = time.perf_counter()
             out = t(sub)
-            self._note_compute(time.perf_counter() - t0,
-                               n_frame_queries(sub))
             groups = out.group_indices(list(self.key_cols)) if len(out) else {}
             empty = out.take(np.asarray([], dtype=np.int64))
             items = []

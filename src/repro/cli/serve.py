@@ -28,21 +28,9 @@ from __future__ import annotations
 
 import argparse
 import json
-from typing import Any, Dict, Optional, Union
+from typing import Any, Dict, Optional
 
 __all__ = ["register", "cmd_serve", "serve_and_drive"]
-
-
-def _int_or_auto(value: str) -> Union[int, str]:
-    if value == "auto":
-        return "auto"
-    return int(value)
-
-
-def _float_or_auto(value: str) -> Union[float, str]:
-    if value == "auto":
-        return "auto"
-    return float(value)
 
 
 def register(subparsers) -> None:
@@ -63,12 +51,10 @@ def register(subparsers) -> None:
     p.add_argument("--requests", type=int, default=200)
     p.add_argument("--clients", type=int, default=4,
                    help="closed-loop client threads")
-    p.add_argument("--max-batch", type=_int_or_auto, default=16,
-                   help="micro-batch flush threshold, or 'auto' to use "
-                        "the plan's autotuned value (from the manifest's "
-                        "measured occupancy history; needs --cache-dir)")
-    p.add_argument("--max-wait-ms", type=_float_or_auto, default=2.0,
-                   help="micro-batch flush timeout (ms), or 'auto'")
+    p.add_argument("--max-batch", type=int, default=16,
+                   help="micro-batch flush threshold")
+    p.add_argument("--max-wait-ms", type=float, default=2.0,
+                   help="micro-batch flush timeout (ms)")
     p.add_argument("--workers", type=int, default=1,
                    help="worker PROCESSES (1 = in-process service, N>1 = "
                         "multi-process fleet over the shared cache dir)")
@@ -100,8 +86,8 @@ def register(subparsers) -> None:
 
 def serve_and_drive(*, pipeline: str, scale: float, cutoff: int,
                     num_results: int, requests: int, clients: int,
-                    max_batch: Union[int, str],
-                    max_wait_ms: Union[float, str], workers: int = 1,
+                    max_batch: int,
+                    max_wait_ms: float, workers: int = 1,
                     exec_workers: int = 4,
                     cache_dir: Optional[str] = None,
                     backend: Optional[str] = None,

@@ -12,7 +12,6 @@ from .precompute import (longest_common_prefix, split_on_prefix,
                          PrecomputeStats)
 from .ir import IRNode, PlanGraph, lower, render_explain
 from .rewrite import OPTIMIZER_PASSES, PassStats
-from .cost import CostContext, CostModel
 from .plan import ExecutionPlan, PlanNode, PlanStats, plan_size
 from .compile_opt import compile_pipeline
 from .measures import Measure, parse_measure, evaluate
@@ -28,7 +27,7 @@ __all__ = [
     "PrefixTrie", "run_with_trie", "PrecomputeStats",
     "ExecutionPlan", "PlanNode", "PlanStats", "plan_size",
     "IRNode", "PlanGraph", "lower", "render_explain",
-    "OPTIMIZER_PASSES", "PassStats", "CostContext", "CostModel",
+    "OPTIMIZER_PASSES", "PassStats",
     "compile_pipeline", "Measure", "parse_measure", "evaluate",
     "Experiment", "ExperimentResult",
 ]

@@ -36,7 +36,6 @@ in ``tests/test_rewrite.py``).
 """
 from __future__ import annotations
 
-import heapq
 import queue as queue_mod
 import random
 import threading
@@ -168,9 +167,6 @@ class _NullRecorder(_Recorder):
 
 _NULL_RECORDER = _NullRecorder()
 
-#: guards IRNode.compute_s / compute_queries (see _timed_exec)
-_COMPUTE_LOCK = threading.Lock()
-
 
 def _effective_inputs(node: IRNode) -> List[IRNode]:
     """The inputs a scheduler must wait for.  Cache-prune: a probing
@@ -221,17 +217,9 @@ def _timed_exec(node: IRNode, ins: List[ColFrame],
                 extra_s: float = 0.0) -> ColFrame:
     """Run one node inside a ``plan.node`` span, and give ``rec`` the
     span's own clock readings (less ``extra_s`` at the start)."""
-    cache = node.cache if hasattr(node.cache, "pop_call_compute") else None
-    if cache is not None:
-        cache.pop_call_compute()         # drop stale counts on this thread
     with trace.timed("plan.node", node=node.label) as t:
         out = _exec_node(node, ins, batch_size)
     rec.add(node.label, shard, t.t0 - extra_s, t.t1)
-    if cache is not None:
-        seconds, queries = cache.pop_call_compute()
-        with _COMPUTE_LOCK:              # one node may run on many shards
-            node.compute_s += seconds
-            node.compute_queries += queries
     return out
 
 
@@ -402,24 +390,15 @@ def run_concurrent(graph: PlanGraph, frame: ColFrame,
         for s in range(n_shards):
             indeg[(node.id, s)] = len(_effective_inputs(node))
 
-    # ready tasks pop in critical-path order: the operand-order pass
-    # stamps each node's sched_priority with its own estimated cost plus
-    # the costliest downstream path, so when more tasks are ready than
-    # workers the long pole starts first.  The monotone sequence number
-    # keeps equal-priority tasks FIFO (and, with priorities all zero —
-    # the cost-blind default — reduces to the previous deque order).
-    ready: List[Tuple[float, int, IRNode, int]] = []
-    seq = 0
+    # ready tasks are submitted in the order they became ready
+    ready: deque = deque()
 
     def complete(node_id: int, s: int) -> None:
-        nonlocal seq
         for child in children.get(node_id, ()):
             key = (child.id, s)
             indeg[key] -= 1
             if indeg[key] == 0:
-                heapq.heappush(ready,
-                               (-child.sched_priority, seq, child, s))
-                seq += 1
+                ready.append((child, s))
 
     for s in range(n_shards):
         complete(graph.source.id, s)
@@ -440,7 +419,7 @@ def run_concurrent(graph: PlanGraph, frame: ColFrame,
 
             def submit_ready() -> None:
                 while ready:
-                    _, _, node, s = heapq.heappop(ready)
+                    node, s = ready.popleft()
                     fut = pool.submit(exec_task, node, s)
                     futures[fut] = (node, s)
 

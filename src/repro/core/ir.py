@@ -79,17 +79,6 @@ class IRNode:
     inline_chain: List["IRNode"] = field(default_factory=list)
     inlined: bool = False                # deferred into the consumer's task
     label: str = ""                      # unique display label
-    # -- cost layer (core/cost.py) annotations -----------------------------
-    cost_est_s: Optional[float] = None   # per-query estimate (seconds)
-    cost_src: Optional[str] = None       # "measured" | "analytic" | "default"
-    sched_priority: float = 0.0          # critical-path rank (operand-order)
-    cache_skip: bool = False             # cache-place: cheaper to recompute
-    backend_override: Optional[str] = None   # cache-place: hot-node promotion
-    #: cumulative miss-path compute of this node's cache calls (seconds,
-    #: queries covered) — the executor adds each call's, so nodes that
-    #: share one store keep their own figures for the cost model
-    compute_s: float = 0.0
-    compute_queries: int = 0
     # -- asynchronous data plane (caching/dataplane.py) ---------------------
     #: plan-stamped: executors issue this node's cache reads on the I/O
     #: pool as soon as the feeding frame exists (False for graphs built
@@ -136,12 +125,6 @@ class PlanGraph:
                              kind="source", stage=None, relation="Q")
         self.nodes: List[IRNode] = [self.source]
         self.terminals: List[IRNode] = []
-        #: cost layer (``core/cost.py``): a ``CostContext`` once the
-        #: planner attaches one; cost-aware passes no-op without it
-        self.cost: Optional[Any] = None
-        #: autotune pass output: recommended executor/serving knobs
-        #: (``n_shards`` / ``max_batch`` / ``max_wait_ms``) with evidence
-        self.tuning: Dict[str, Any] = {}
 
     def _take_id(self) -> int:
         i = self._next_id
@@ -279,18 +262,6 @@ def _node_line(rec: Dict[str, Any]) -> str:
     touched = rec.get("touched_by") or []
     if touched:
         parts.append(f"passes={','.join(touched)}")
-    est = rec.get("cost_est_s")
-    if est is not None:
-        cost = f"cost[est={float(est) * 1e3:.3f}ms"
-        act = rec.get("cost_act_s")
-        if act is not None:
-            cost += f" act={float(act) * 1e3:.3f}ms"
-        src = rec.get("cost_src")
-        if src:
-            cost += f" src={src}"
-        parts.append(cost + "]")
-    if rec.get("cache_skip"):
-        parts.append("(cache-skipped)")
     onl = rec.get("online")
     if onl:
         parts.append("online[p50=%.2fms p99=%.2fms n=%d]"

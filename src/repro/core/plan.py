@@ -48,9 +48,6 @@ from dataclasses import dataclass, field
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
                     Union)
 
-from .cost import (CostContext, CostModel, annotate_node_actuals,
-                   compute_node_fingerprints, fold_costs, should_prefetch,
-                   stage_fingerprint, _round_cost)
 from .executor import (_Recorder, resolve_n_shards, run_concurrent,
                        run_sequential, run_warm)
 from .frame import ColFrame
@@ -58,8 +55,7 @@ from .ir import IRNode, PlanGraph, lower, plan_size, render_explain
 from .pipeline import Transformer, pipeline_hash
 from .precompute import PrecomputeStats, longest_common_prefix
 from . import trace
-from .rewrite import (PLACEMENT_PASSES, POST_MEMO_PASSES, PassStats,
-                      resolve_passes, run_pass)
+from .rewrite import POST_MEMO_PASSES, PassStats, resolve_passes, run_pass
 
 __all__ = ["ExecutionPlan", "PlanNode", "PlanStats", "plan_size"]
 
@@ -79,13 +75,6 @@ class PlanStats(PrecomputeStats):
     cache_prefetched: int = 0
     node_times_s: Dict[str, float] = field(default_factory=dict)
     node_exec_counts: Dict[str, int] = field(default_factory=dict)
-    #: raw wrapped-transformer seconds (and the queries they covered)
-    #: spent on cached nodes' miss paths this run — the recompute cost
-    #: the fingerprint-keyed EWMA folds for cached nodes, since their
-    #: ``node_times_s`` is dominated by store round trips (see
-    #: ``caching.base.CacheStats.compute_s``)
-    node_compute_s: Dict[str, float] = field(default_factory=dict)
-    node_compute_queries: Dict[str, int] = field(default_factory=dict)
     wall_time_s: float = 0.0
     n_queries: int = 0                   # rows in the query frame
     # -- online serving (filled by PipelineService, see serve/service.py) ----
@@ -121,6 +110,46 @@ class PlanStats(PrecomputeStats):
                 f"saved={self.stage_invocations_saved} "
                 f"cache_hits={self.cache_hits} "
                 f"wall={self.wall_time_s:.3f}s{opt}{extra})")
+
+
+def stage_fingerprint(stage: Any) -> str:
+    """A stage's own provenance fingerprint: ``fingerprint()`` where
+    derivable, else a digest of its repr."""
+    from ..caching.auto import derive_fingerprint
+    from ..caching.provenance import combine_fingerprints
+    return derive_fingerprint(stage) \
+        or combine_fingerprints("sig", repr(stage))
+
+
+def compute_node_fingerprints(graph: PlanGraph) -> Dict[int, str]:
+    """Provenance fingerprint per node (id-keyed): the stage fingerprint
+    folded over the input nodes' fingerprints, bottom-up.
+
+    For *commutative* combine nodes the input fingerprints fold in
+    sorted order, so ``a + b`` and ``b + a`` carry the same fingerprint
+    and share cache-manifest provenance."""
+    from ..caching.provenance import combine_fingerprints
+    fps: Dict[int, str] = {
+        graph.source.id: combine_fingerprints("plan-source")}
+    # graph.nodes is topological — every input precedes its consumer
+    for node in graph.nodes:
+        if node.kind == "source":
+            continue
+        in_fps = [fps[i.id] for i in node.inputs]
+        if node.kind == "combine" and getattr(node.stage, "commutative",
+                                              False):
+            # the binary stage's own signature() embeds its operands'
+            # signatures *in order*; the operands are already captured
+            # by the (sorted) input fingerprints, so key the stage by
+            # class alone — same symmetrization canon_key uses
+            stage_fp = combine_fingerprints("combine",
+                                            type(node.stage).__name__)
+            in_fps = sorted(in_fps)
+        else:
+            stage_fp = stage_fingerprint(node.stage)
+        fps[node.id] = combine_fingerprints(
+            "node", node.kind, stage_fp, *in_fps)
+    return fps
 
 
 def _row_level(stage: Any) -> bool:
@@ -256,8 +285,8 @@ class ExecutionPlan:
         overlapping compute; miss-path writes move to a bounded
         write-behind queue flushed on ``close()``/``drain()``.  Results
         are per-qid bit-identical with and without it (property-tested);
-        gated per node by :func:`repro.core.cost.should_prefetch` and
-        globally by ``REPRO_PREFETCH=0`` / ``REPRO_WRITE_BEHIND=0``.
+        switched off globally by ``REPRO_PREFETCH=0`` /
+        ``REPRO_WRITE_BEHIND=0``.
     """
 
     @trace.spanned("plan.build")
@@ -287,9 +316,7 @@ class ExecutionPlan:
         self._plan_manifest_path: Optional[str] = None
 
         # -- layer 2: optimizer (structural pre-memo passes) ---------------
-        pre = [name for name in passes
-               if name not in POST_MEMO_PASSES
-               and name not in PLACEMENT_PASSES and name != "operand-order"]
+        pre = [name for name in passes if name not in POST_MEMO_PASSES]
         self.pass_stats: List[PassStats] = [
             run_pass(self.graph, name) for name in pre]
         if "cse" in pre and any(p.name == "pushdown" and p.cutoffs_pushed
@@ -301,25 +328,13 @@ class ExecutionPlan:
             self.pass_stats += [run_pass(self.graph, name)
                                 for name in ("normalize", "cse")
                                 if name in pre]
-        # the cost-aware ordering pass runs last of the pre-memo passes,
-        # after the re-round, so it orders the final structural DAG
-        if "operand-order" in passes:
-            self._ensure_cost_ctx()
-            self.pass_stats.append(run_pass(self.graph, "operand-order"))
 
         if (cache_dir is not None or memo_factory is not None
                 or cache_backend is not None):
-            # cache placement must decide *before* memos are opened
-            if "cache-place" in passes:
-                self._ensure_cost_ctx()
-                self.pass_stats.append(run_pass(self.graph, "cache-place"))
             self._insert_memos()
             # post-memo passes consult the freshly opened cache manifests
-            # (cache-prune) and the manifest's run history (autotune)
-            post = [name for name in passes if name in POST_MEMO_PASSES]
-            if "autotune" in post:
-                self._ensure_cost_ctx()
-            self.pass_stats += [run_pass(self.graph, name) for name in post]
+            self.pass_stats += [run_pass(self.graph, name) for name in passes
+                                if name in POST_MEMO_PASSES]
         self._label_nodes()
         # the self-describing record is built lazily — fingerprinting
         # every node is only worth paying for when something consumes it
@@ -369,71 +384,12 @@ class ExecutionPlan:
         transformer fingerprint folded over the fingerprints of its
         input nodes, so a config/code change anywhere upstream changes
         every downstream node's fingerprint (``caching/provenance.py``).
-        Commutative combine operands fold in sorted order, so the
-        fingerprints — and everything keyed on them: cache provenance,
-        measured costs — are invariant under the ``operand-order``
-        rewrite.  Deterministic across processes."""
+        Commutative combine operands fold in sorted order, so ``a + b``
+        and ``b + a`` share fingerprints.  Deterministic across
+        processes."""
         if self._node_fps is None:
             self._node_fps = compute_node_fingerprints(self.graph)
         return self._node_fps
-
-    # -- cost layer --------------------------------------------------------
-    def _ensure_cost_ctx(self) -> None:
-        """Attach a :class:`~repro.core.cost.CostContext` as
-        ``graph.cost`` (once): the measured-cost EWMA table and run
-        history from the prior plan manifest, plus the microbenchmarked
-        cache round-trip of the resolved backend when this plan will
-        insert caches.  Consumed by the ``operand-order`` /
-        ``cache-place`` / ``autotune`` passes."""
-        if self.graph.cost is not None:
-            return
-        fps = self.node_fingerprints()
-        record: Optional[Dict[str, Any]] = None
-        history: List[Dict[str, Any]] = []
-        if self.cache_dir is not None:
-            from ..caching.provenance import combine_fingerprints
-            plan_id = combine_fingerprints(
-                "plan", *[fps[t.id] for t in self.graph.terminals])
-            prior = os.path.join(self.cache_dir, "plans", f"{plan_id}.json")
-            if os.path.exists(prior):
-                try:
-                    import json
-                    with open(prior, "r", encoding="utf-8") as f:
-                        record = json.load(f)
-                    history = [r for r in record.get("runs", [])
-                               if isinstance(r, dict)]
-                except Exception:
-                    record = None
-        backend = round_trip = None
-        if (self.cache_dir is not None or self.cache_backend is not None
-                or self._memo_factory is not None):
-            from ..caching.backends import (measure_round_trip,
-                                            resolve_backend_name)
-            try:
-                # with no explicit selector each cache family picks its
-                # own default, so there is no single name to promote —
-                # ctx.backend stays None (cache-place still *skips* using
-                # the measured round trip of a representative store)
-                resolved = resolve_backend_name(self.cache_backend,
-                                                default="sqlite")
-                round_trip = measure_round_trip(resolved)
-                if self.cache_backend is not None:
-                    backend = resolved
-            except Exception:
-                backend = round_trip = None
-        self.graph.cost = CostContext(
-            model=CostModel.from_manifest(record), fps=fps,
-            backend=backend, round_trip_s=round_trip, history=history)
-
-    def tuning(self) -> Dict[str, Any]:
-        """Knob values chosen by the ``autotune`` pass (``n_shards``,
-        ``max_batch``, ``max_wait_ms`` — whichever had evidence), flat
-        ``{knob: value}``.  ``serve`` consumes these via
-        ``max_batch="auto"``; offline callers can forward ``n_shards``
-        to :meth:`run`.  Empty when autotune did not run or had no
-        evidence."""
-        return {k: v.get("value") for k, v in self.graph.tuning.items()
-                if isinstance(v, dict)}
 
     # -- planner-inserted memoization --------------------------------------
     def _insert_memos(self) -> None:
@@ -447,17 +403,11 @@ class ExecutionPlan:
         if self.cache_budget is not None:
             kwargs["budget"] = self.cache_budget
         fps = self.node_fingerprints()
-        nodes = [n for n in self.graph.nodes
-                 if n.kind == "stage" and not n.cache_skip]  # cache-place
         shared = share_keys(self.graph, fps)
-        # a store that cache-place promotes for one of its nodes is
-        # promoted for all of them
-        promoted: Dict[str, str] = {}
-        for node in nodes:
-            if node.id in shared and node.backend_override is not None:
-                promoted.setdefault(shared[node.id], node.backend_override)
         opened: Dict[str, Any] = {}
-        for node in nodes:
+        for node in self.graph.nodes:
+            if node.kind != "stage":
+                continue
             store = shared.get(node.id)
             if store in opened:
                 # one instance per store: the write-behind overlay makes
@@ -469,10 +419,9 @@ class ExecutionPlan:
                 # a row-level stage's store is keyed by its share key;
                 # any other's by the node's full structural position, so
                 # the same stage under different prefixes never
-                # collides — the commutative-canonical key (when the
-                # normalize pass ran), stable under operand-order swaps:
-                # a reorder must never cool a warm cache.  sha256 (not
-                # hash()) so the path is stable across processes
+                # collides — the commutative-canonical key when the
+                # normalize pass ran.  sha256 (not hash()) so the path is
+                # stable across processes
                 basis = store if store is not None else \
                     node.canon_key if node.canon_key is not None \
                     else node.key
@@ -489,9 +438,6 @@ class ExecutionPlan:
                       # deterministic transformers (hand-wrapped caches
                       # keep synchronous puts unless asked)
                       "async_writes": True}
-            override = promoted.get(store, node.backend_override)
-            if override is not None:
-                wanted["backend"] = override
             node.cache = factory(node.stage, path,
                                  **_accepted_kwargs(factory, wanted))
             if store is not None:
@@ -507,19 +453,13 @@ class ExecutionPlan:
         return list(seen.values())
 
     def _stamp_prefetch(self) -> None:
-        """Mark which memoized nodes the executors should prefetch:
-        plan opt-in (``prefetch=``), a global kill switch
-        (``REPRO_PREFETCH=0``), the backend's ``prefetchable`` flag
-        (memory-speed tiers decline), and the cost gate
-        (:func:`~repro.core.cost.should_prefetch` on the measured store
-        round trip).  Purely a scheduling decision — results are
-        identical either way."""
+        """Mark which memoized nodes the executors should prefetch: plan
+        opt-in (``prefetch=``), a global kill switch
+        (``REPRO_PREFETCH=0``), and the cache's ``prefetchable`` flag
+        (memory-speed stores decline).  Purely a scheduling decision —
+        results are identical either way."""
         from ..caching.dataplane import prefetch_default
         if not (self.prefetch and prefetch_default()):
-            return
-        cost = self.graph.cost
-        round_trip = cost.round_trip_s if cost is not None else None
-        if not should_prefetch(round_trip):
             return
         for node in self.graph.nodes:
             cache = node.cache
@@ -563,10 +503,6 @@ class ExecutionPlan:
                 "inlined": node.inlined,
                 "probe_input": node.probe_input.id
                                if node.probe_input is not None else None,
-                "cost_est_s": _round_cost(node.cost_est_s)
-                              if node.cost_est_s is not None else None,
-                "cost_src": node.cost_src,
-                "cache_skip": node.cache_skip,
             })
         agg = self._aggregate_pass_stats()
         return {
@@ -583,12 +519,8 @@ class ExecutionPlan:
                 "nodes_eliminated": agg["nodes_eliminated"],
                 "cutoffs_pushed": agg["cutoffs_pushed"],
                 "nodes_marked_prunable": agg["nodes_marked_prunable"],
-                "caches_skipped": agg["caches_skipped"],
-                "caches_promoted": agg["caches_promoted"],
-                "inputs_reordered": agg["inputs_reordered"],
                 "pass_stats": [p.as_dict() for p in self.pass_stats],
             },
-            "tuning": dict(self.graph.tuning),
             "runs": [],
         }
 
@@ -599,30 +531,14 @@ class ExecutionPlan:
             "cutoffs_pushed": sum(p.cutoffs_pushed for p in self.pass_stats),
             "nodes_marked_prunable": sum(p.nodes_marked_prunable
                                          for p in self.pass_stats),
-            "caches_skipped": sum(p.caches_skipped for p in self.pass_stats),
-            "caches_promoted": sum(p.caches_promoted
-                                   for p in self.pass_stats),
-            "inputs_reordered": sum(p.inputs_reordered
-                                    for p in self.pass_stats),
         }
 
     def explain(self) -> str:
         """ASCII rendering of the optimized plan: one tree per pipeline
-        with per-node id, relation, provenance fingerprint, cache family,
-        the optimizer passes that touched the node and — when the cost
-        layer ran — estimated-vs-actual per-query cost columns
-        (``cost[est=… act=… src=…]``).  Byte-identical to ``repro plan
-        explain`` over this plan's manifest: actuals come from the same
-        persisted EWMA table the CLI reads."""
-        record = self.to_record()
-        if self._plan_manifest_path is None and self.stats is not None \
-                and self.stats.node_times_s:
-            # no manifest to carry measured costs (in-memory plan):
-            # overlay this run's actuals so explain() still shows them
-            import copy
-            record = copy.deepcopy(record)
-            fold_costs(record, self.stats)
-        return render_explain(record)
+        with per-node id, relation, provenance fingerprint, cache family
+        and the optimizer passes that touched the node.  Byte-identical
+        to ``repro plan explain`` over this plan's manifest."""
+        return render_explain(self.to_record())
 
     def to_record(self) -> Dict[str, Any]:
         """The plan-manifest record (see ``_build_record``), built on
@@ -650,10 +566,6 @@ class ExecutionPlan:
                 record["created_at"] = old.get("created_at",
                                                record["created_at"])
                 record["runs"] = list(old.get("runs", []))
-                # measured per-node costs survive re-planning: they are
-                # fingerprint-keyed, so stale entries simply never match
-                record["costs"] = dict(old.get("costs") or {})
-                annotate_node_actuals(record)
             except Exception:
                 pass
         self._plan_manifest_path = save_plan_manifest(self.cache_dir, record)
@@ -679,22 +591,8 @@ class ExecutionPlan:
                 "wall_time_s": round(stats.wall_time_s, 4),
                 "n_queries": stats.n_queries,
             }
-            online = stats.online or {}
-            if online:
-                run["online"] = {k: online[k] for k in (
-                    "batch_occupancy", "queue_depth_p50", "queue_depth_p99",
-                    "max_batch", "max_wait_ms") if k in online}
             runs.append(run)
             del runs[:-50]               # keep the tail bounded
-            # fold this run's measured per-node times into the
-            # fingerprint-keyed EWMA cost table (core/cost.py) — the
-            # next compile's cost model reads it back
-            fold_costs(record, stats)
-            if self._record is not None:
-                # keep the in-memory record (explain()) in sync with the
-                # persisted EWMA so both render identical actual columns
-                self._record["costs"] = record.get("costs", {})
-                annotate_node_actuals(self._record)
             from ..caching.backends import atomic_write_bytes
             atomic_write_bytes(
                 self._plan_manifest_path,
@@ -764,7 +662,6 @@ class ExecutionPlan:
         else:
             workers = min(32, shards) if shards > 1 else 1
         cache_base = self._cache_counters()
-        compute_base = self._compute_counters()
         stats = self._new_stats()
         stats.n_queries = len(frame)
         rec = _Recorder()
@@ -776,7 +673,6 @@ class ExecutionPlan:
             stats.n_shards = len(bounds)
             stats.n_workers = workers
         self._fill_exec_stats(stats, rec)
-        self._fill_compute_stats(stats, compute_base)
         self._finalize_stats(stats, cache_base, t0)
         if stats.n_shards > 1 or stats.n_workers > 1:
             busy = sum(b - a for _, _, a, b in rec.records)
@@ -802,14 +698,12 @@ class ExecutionPlan:
         t0 = time.perf_counter()
         frame = ColFrame.coerce(queries)
         cache_base = self._cache_counters()
-        compute_base = self._compute_counters()
         stats = self._new_stats()
         stats.n_queries = len(frame)
         rec = _Recorder()
         run_warm(self.graph, frame, batch_size, chunk_rows=chunk_rows,
                  rec=rec)
         self._fill_exec_stats(stats, rec)
-        self._fill_compute_stats(stats, compute_base)
         self._finalize_stats(stats, cache_base, t0)
         return stats
 
@@ -873,19 +767,3 @@ class ExecutionPlan:
                 misses += cs.misses
                 prefetched += getattr(cs, "prefetched", 0)
         return hits, misses, prefetched
-
-    def _compute_counters(self) -> Dict[str, Tuple[float, int]]:
-        """Cumulative raw-compute counters per *cached* node label (see
-        ``CacheTransformer._note_compute``) — snapshot before a run,
-        delta after."""
-        return {node.label: (node.compute_s, node.compute_queries)
-                for node in self.graph.nodes
-                if getattr(node.cache, "stats", None) is not None
-                and node.label is not None}
-
-    def _fill_compute_stats(self, stats: PlanStats,
-                            base: Dict[str, Tuple[float, int]]) -> None:
-        for label, (s1, q1) in self._compute_counters().items():
-            s0, q0 = base.get(label, (0.0, 0))
-            stats.node_compute_s[label] = s1 - s0
-            stats.node_compute_queries[label] = q1 - q0

@@ -23,26 +23,13 @@ score reported in EXPERIMENTS.md §Perf.
 """
 from __future__ import annotations
 
-import math
 import re
 from dataclasses import asdict, dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 PEAK_FLOPS = 197e12         # bf16 / chip
 HBM_BW = 819e9              # bytes/s / chip
 ICI_BW = 50e9               # bytes/s / link
-
-# host-side roofline priors for the plan compiler's cost model
-# (core/cost.py): sustained throughput of the *Python/numpy host path*
-# IR stages actually run on, far below chip peak.  Deliberately rough —
-# these only seed cost estimates until real measurements replace them.
-HOST_PEAK_FLOPS = 2e10      # sustained host FLOP/s (BLAS-ish)
-HOST_MEM_BW = 5e9           # bytes/s effective host streaming
-#: per-query Python dispatch floor added to every host estimate: frame
-#: plumbing and interpreter overhead dominate tiny workloads, and an
-#: optimistic prior must never claim a stage is cheaper than a cache
-#: round-trip (only *measurements* may justify dropping a cache)
-HOST_DISPATCH_OVERHEAD_S = 5e-5
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -56,60 +43,10 @@ _COLLECTIVE_RE = re.compile(
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(")
 
-__all__ = ["PEAK_FLOPS", "HBM_BW", "ICI_BW", "HOST_PEAK_FLOPS",
-           "HOST_MEM_BW", "HOST_DISPATCH_OVERHEAD_S",
+__all__ = ["PEAK_FLOPS", "HBM_BW", "ICI_BW",
            "parse_collective_bytes", "RooflineReport",
-           "analyze_compiled", "estimate_stage_cost", "lm_model_flops",
+           "analyze_compiled", "lm_model_flops",
            "gnn_model_flops", "recsys_model_flops", "model_flops_for"]
-
-
-def estimate_stage_cost(stage) -> Optional[float]:
-    """Analytic per-query cost prior (seconds) for kernel-backed
-    pipeline stages — the plan compiler's cold-start estimate before
-    any run has been measured (``core/cost.py``).
-
-    Duck-typed on the stage class name so this module never imports the
-    IR layer: a ``DenseRetriever`` costs one row of the blocked matmul
-    + top-k against its corpus matrix, a ``BM25Retriever`` one TAAT
-    postings traversal.  The figure is
-    ``HOST_DISPATCH_OVERHEAD_S + max(flops / HOST_PEAK_FLOPS,
-    bytes / HOST_MEM_BW)`` — the host roofline plus the per-query
-    Python dispatch floor.  Returns ``None`` for stages with no
-    analytic model (generic transformers fall back to the cost model's
-    defaults).
-    """
-    name = type(stage).__name__
-    if name == "DenseRetriever":
-        matrix = getattr(getattr(stage, "index", None), "matrix", None)
-        shape = getattr(matrix, "shape", None)
-        if not shape or len(shape) != 2:
-            return None
-        n_docs, dim = int(shape[0]), int(shape[1])
-        itemsize = int(getattr(matrix, "itemsize", 4) or 4)
-        k = int(getattr(stage, "num_results", 100))
-        flops = 2.0 * n_docs * dim            # one query row × corpus
-        byts = float(n_docs * dim * itemsize) # stream the matrix
-        topk = float(n_docs) * max(1.0, math.log2(max(2, k)))
-        return HOST_DISPATCH_OVERHEAD_S + max(
-            (flops + topk) / HOST_PEAK_FLOPS, byts / HOST_MEM_BW)
-    if name == "BM25Retriever":
-        index = getattr(stage, "index", None)
-        n_docs = getattr(index, "n_docs", None)
-        if n_docs is None:
-            docnos = getattr(index, "docnos", None)
-            n_docs = len(docnos) if docnos is not None else None
-        if not n_docs:
-            return None
-        # TAAT: ~q_terms postings lists, each a fraction of the corpus;
-        # model ≈ 4 query terms × 10% selectivity × (ids+tfs+score work)
-        postings = 4 * 0.1 * float(n_docs)
-        flops = 8.0 * postings                # idf/tf saturation per hit
-        byts = 12.0 * postings                # int32 id + f32 tf + accum
-        k = int(getattr(stage, "num_results", 1000))
-        sort = float(n_docs) * max(1.0, math.log2(max(2, min(k, n_docs))))
-        return HOST_DISPATCH_OVERHEAD_S + max(
-            (flops + sort) / HOST_PEAK_FLOPS, byts / HOST_MEM_BW)
-    return None
 
 
 def _shape_bytes(shape_str: str) -> int:

@@ -63,11 +63,10 @@ class ServeConfig:
     prefetch: bool = True
 
     # -- micro-batching / executor knobs ------------------------------------
-    #: positive int, or ``"auto"`` to take the compiled plan's autotuned
-    #: value (derived from the manifest's measured occupancy history)
-    max_batch: Union[int, str] = 16
-    #: milliseconds, or ``"auto"`` (see ``max_batch``)
-    max_wait_ms: Union[float, str] = 2.0
+    #: positive int
+    max_batch: int = 16
+    #: milliseconds
+    max_wait_ms: float = 2.0
     #: thread-pool size of each service's streaming executor
     exec_workers: int = 4
     queue_capacity: int = 1024
@@ -101,10 +100,9 @@ class ServeConfig:
                              f"got {self.routing!r}")
         for knob in ("max_batch", "max_wait_ms"):
             v = getattr(self, knob)
-            if isinstance(v, str) and v != "auto":
-                raise ValueError(f"{knob} must be a number or 'auto', "
-                                 f"got {v!r}")
-        if not isinstance(self.max_batch, str) and int(self.max_batch) < 1:
+            if isinstance(v, str):
+                raise ValueError(f"{knob} must be a number, got {v!r}")
+        if int(self.max_batch) < 1:
             raise ValueError(f"max_batch must be >= 1, "
                              f"got {self.max_batch}")
         if self.backend is not None:
@@ -227,7 +225,6 @@ def drive_closed_loop(config: Any = None, *, requests: int = 200,
             "pipeline": cfg.pipeline,
             "description": scenario.description,
             "optimize": cfg.optimize,
-            # the resolved values ("auto" resolves at service build)
             "max_batch": getattr(svc, "max_batch", cfg.max_batch),
             "max_wait_ms": getattr(svc, "max_wait_ms", cfg.max_wait_ms),
             "workers": cfg.workers,

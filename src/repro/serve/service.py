@@ -110,13 +110,6 @@ class PipelineService:
         requests are pending or ``max_wait_ms`` after its first
         request, whichever first.  ``max_wait_ms=0`` disables the
         batching delay (each dispatch takes whatever is queued).
-        Either knob accepts ``"auto"``: the value the compiled plan's
-        ``autotune`` pass derived from the manifest's measured
-        batch-occupancy / queue-depth history (falling back to the
-        defaults when there is no evidence yet).  Each service run
-        records its online stats back into the plan manifest on
-        ``close()``, so an ``"auto"`` service self-tunes across
-        restarts.
     max_workers:
         Thread-pool size of the streaming executor (DAG branches and
         in-flight micro-batches run concurrently on it).
@@ -130,8 +123,8 @@ class PipelineService:
                  cache_backend: Optional[str] = None,
                  on_stale: str = "error",
                  optimize: Union[str, Sequence[str], None] = "all",
-                 max_batch: Union[int, str] = 32,
-                 max_wait_ms: Union[float, str] = 2.0,
+                 max_batch: int = 32,
+                 max_wait_ms: float = 2.0,
                  max_workers: int = 4, queue_capacity: int = 1024,
                  batch_size: Optional[int] = None,
                  reservoir_capacity: int = 4096,
@@ -141,11 +134,6 @@ class PipelineService:
                                   cache_backend=cache_backend,
                                   on_stale=on_stale, optimize=optimize,
                                   prefetch=prefetch)
-        tuned = self.plan.tuning()
-        if max_batch == "auto":
-            max_batch = int(tuned.get("max_batch", 32))
-        if max_wait_ms == "auto":
-            max_wait_ms = float(tuned.get("max_wait_ms", 2.0))
         self.stats = ServiceStats(reservoir_capacity)
         self._exec = StreamingExecutor(
             self.plan.graph, batch_size=batch_size, max_batch=max_batch,
@@ -153,7 +141,6 @@ class PipelineService:
             queue_capacity=queue_capacity, on_batch=self._on_batch)
         self.max_batch = self._exec.max_batch
         self.max_wait_ms = float(max_wait_ms)
-        self._compute_base = self.plan._compute_counters()
         self._cache_base = self.plan._cache_counters()
         self._closed = False
 
@@ -212,17 +199,12 @@ class PipelineService:
         stats.node_exec_counts = {label: int(d["executions"])
                                   for label, d in per_node.items()}
         # approximate total per-node seconds from the online latency
-        # reservoirs (executions × p50) — what _record_run folds into
-        # the manifest's measured cost table, so a served plan's costs
-        # inform the next compile exactly like an offline run's
+        # reservoirs (executions × p50)
         stats.node_times_s = {
             label: int(d["executions"]) * float(d["p50_ms"]) / 1e3
             for label, d in per_node.items() if d["executions"]}
         stats.n_queries = int(s.requests)
         stats.nodes_executed = len(per_node)
-        # cached nodes fold their raw miss-path compute time instead of
-        # the store-dominated wrapper latency (see cost.fold_costs)
-        self.plan._fill_compute_stats(stats, self._compute_base)
         stats.cache_hits = s.cache_hits
         stats.cache_misses = s.cache_misses
         # staged-served subset of the hits (dataplane prefetch) — read
@@ -267,9 +249,7 @@ class PipelineService:
         if self.plan._plan_manifest_path is not None \
                 and self._exec.stats.requests:
             try:
-                # persist this service run (incl. online batch stats) to
-                # the plan manifest: the next compile's autotune pass
-                # reads it back — this is what makes "auto" self-tuning
+                # append this service run to the plan manifest's log
                 self.plan._record_run(self.plan_stats())
             except Exception:
                 pass

@@ -40,17 +40,19 @@ class Retriever(Transformer):
 
 class PairScorer(Transformer):
     """Pointwise: a pair's score depends on its query and text alone.
-    ``pairs`` lists every (query, docno) it computed, in order."""
+    ``pairs`` lists every (query, docno) it computed, in order, and
+    ``calls`` the number of rows of each call."""
 
     input_columns = frozenset({"qid", "query", "docno", "text"})
     key_columns = ("query", "docno")
     value_columns = ("score",)
 
     def __init__(self):
-        self.pairs = []
+        self.pairs, self.calls = [], []
 
     def transform(self, inp):
         queries, texts = inp["query"].tolist(), inp["text"].tolist()
+        self.calls.append(len(inp))
         self.pairs.extend(zip(queries, inp["docno"].tolist()))
         score = np.asarray([sum(map(ord, q + t)) % 97 / 7.0
                             for q, t in zip(queries, texts)])

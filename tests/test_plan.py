@@ -586,10 +586,7 @@ def test_pointwise_scorer_shares_one_store_across_depths(tmp_path, run_kw):
             loader = pipelines[0].stages[1]
             assert len({id(c) for c in _stage_caches(plan, loader)}) == 1
             outs, stats = plan.run(QUERIES, **run_kw)
-            labels = [n.label for n in plan.graph.nodes
-                      if n.stage is scorer]
-            computed = [stats.node_compute_queries[label]
-                        for label in labels]
+            computed = list(scorer.calls)
             _, again = plan.run(QUERIES, **run_kw)
     finally:
         sys.setswitchinterval(interval)
@@ -602,12 +599,12 @@ def test_pointwise_scorer_shares_one_store_across_depths(tmp_path, run_kw):
     rows = n_q * sum(DEPTHS)
     assert stats.cache_misses == 2 * n_q * top + n_q
     assert stats.cache_hits == 2 * (rows - n_q * top)
-    # each scorer node keeps its own misses' compute: in order, every
-    # query had passages the earlier depths had not scored; racing, the
-    # nodes split the queries that computed anything
+    # in order, each depth scores only the passages the shallower depths
+    # had not; racing, the calls split the same pairs among them
     if not run_kw:
-        assert computed == [n_q] * len(DEPTHS)
-    assert n_q <= sum(computed) <= n_q * len(DEPTHS)
+        assert computed == [n_q * (k - j) for j, k in
+                            zip((0,) + DEPTHS[:-1], DEPTHS)]
+    assert sum(computed) == n_q * top
     assert again.cache_misses == 0
     assert again.cache_hits == 2 * rows + n_q
     assert len(scorer.pairs) == n_q * top
@@ -617,13 +614,10 @@ _SHARED_STORE_SCRIPT = """
 import sys
 sys.path.insert(0, sys.argv[2])
 from _depth_grid import QUERIES, PairScorer, grid
-from repro.core import ExecutionPlan, OPTIMIZER_PASSES
+from repro.core import ExecutionPlan
 scorer = PairScorer()
-# cache-place left out: these toy stages recompute about as fast as a
-# store round trip, so it could drop a store the test means to reopen
-passes = [p for p in OPTIMIZER_PASSES if p != "cache-place"]
 with ExecutionPlan(grid(scorer), cache_dir=sys.argv[1],
-                   on_stale="error", optimize=passes) as plan:
+                   on_stale="error") as plan:
     outs, stats = plan.run(QUERIES)
 print(stats.cache_hits, stats.cache_misses, len(scorer.pairs))
 for out in outs:
@@ -748,3 +742,147 @@ def test_non_row_level_caches_keep_per_position_stores(tmp_path, make):
     for got, w in zip(outs, want):
         assert got.sort_values(SORT).equals(
             w.sort_values(SORT), cols=list(w.columns), rtol=0, atol=0)
+
+
+# ---------------------------------------------------------------------------
+# plans depend on structure alone: no run history, no timing
+# ---------------------------------------------------------------------------
+
+def test_fingerprints_invariant_under_operand_swap():
+    """``a + b`` and ``b + a`` get one fingerprint (and so one cache
+    provenance), with no optimizer pass involved."""
+    p1 = ExecutionPlan([make_retriever("A") + make_retriever("B", base=8.0)],
+                       optimize="none")
+    p2 = ExecutionPlan([make_retriever("B", base=8.0) + make_retriever("A")],
+                       optimize="none")
+    fp1 = p1.node_fingerprints()[p1.graph.terminals[0].id]
+    fp2 = p2.node_fingerprints()[p2.graph.terminals[0].id]
+    assert fp1 == fp2
+
+
+def _timed_grid(delay):
+    """The depth grid with an uncached last stage that sleeps
+    ``delay["s"]`` seconds per call, so runs differ in wall time."""
+    import time
+    from _depth_grid import PairScorer, grid
+
+    def fn(inp):
+        time.sleep(delay["s"])
+        return inp
+    pause = GenericTransformer(fn, "pause")
+    return [p >> pause for p in grid(PairScorer())]
+
+
+def _plan_view(plan):
+    return (plan.explain(), plan.to_record()["nodes"],
+            [(n.label, n.prefetch) for n in plan.graph.nodes])
+
+
+@pytest.mark.parametrize("n_runs", [0, 1, 5])
+def test_explain_is_the_same_whatever_ran_before(tmp_path, n_runs):
+    """Planning the same pipelines over one cache directory gives the
+    same plan with no history, after one run, and after five runs of
+    differing wall times; every run is still logged.  No stage here can
+    be deferred behind a warm store, so only history could change it."""
+    import json
+    from _depth_grid import QUERIES
+    delay = {"s": 0.0}
+    with ExecutionPlan(_timed_grid(delay), cache_dir=str(tmp_path)) as plan:
+        first = _plan_view(plan)
+        manifest = plan._plan_manifest_path
+        for i in range(n_runs):
+            delay["s"] = 0.004 * i
+            plan.run(QUERIES)
+    with ExecutionPlan(_timed_grid(delay), cache_dir=str(tmp_path)) as plan:
+        assert _plan_view(plan) == first
+        assert plan._plan_manifest_path == manifest
+    with open(manifest) as f:
+        record = json.load(f)
+    assert len(record["runs"]) == n_runs
+    assert "costs" not in record and "tuning" not in record
+
+
+@pytest.mark.parametrize("backend,prefetch,stamped", [
+    ("memory", True, False), ("pickle", True, True), ("dbm", True, True),
+    ("sqlite", True, True), ("tiered:sqlite", True, True),
+    ("mmap:sqlite", True, False), ("sqlite", False, False)])
+def test_prefetch_stamps_follow_the_cache(tmp_path, monkeypatch, backend,
+                                          prefetch, stamped):
+    """A planner cache is prefetched iff the plan asks for it and the
+    cache declares itself ``prefetchable``: memory-speed stores (the
+    memory LRU, the mmap snapshot) decline, every other store accepts,
+    whatever its measured speed on this host."""
+    from _depth_grid import PairScorer, grid
+    monkeypatch.delenv("REPRO_PREFETCH", raising=False)
+    with ExecutionPlan(grid(PairScorer()), cache_dir=str(tmp_path),
+                       cache_backend=backend, prefetch=prefetch) as plan:
+        cached = [n for n in plan.graph.nodes if n.cache is not None]
+        assert cached
+        for n in cached:
+            assert n.cache.prefetchable is (backend.split(":")[0]
+                                            not in ("memory", "mmap"))
+            assert n.prefetch is (prefetch and n.cache.prefetchable)
+            assert n.prefetch is stamped
+
+
+@pytest.mark.parametrize("backend", ["memory", "pickle", "dbm", "sqlite"])
+def test_depth_grid_caches_on_every_store(tmp_path, backend):
+    """On each store the depth grid caches its retriever, loader and
+    scorer and never a cutoff, the loader and the scorer keep one store
+    each across the depths, and results equal an uncached plan's bit for
+    bit, cold and warm."""
+    from _depth_grid import DEPTHS, QUERIES, PairScorer, Retriever, grid
+    from repro.core import RankCutoff
+    from repro.ir import TextLoader
+    want, _ = ExecutionPlan(grid(PairScorer())).run(QUERIES)
+    scorer = PairScorer()
+    with ExecutionPlan(grid(scorer), cache_dir=str(tmp_path),
+                       cache_backend=backend) as plan:
+        nodes = [n for n in plan.graph.nodes if n.kind == "stage"]
+        by_kind = {}
+        for n in nodes:
+            by_kind.setdefault(type(n.stage), []).append(n)
+        assert len(by_kind[Retriever]) == 1
+        assert by_kind[Retriever][0].cache is not None
+        assert len(by_kind[RankCutoff]) == len(DEPTHS)
+        assert all(n.cache is None for n in by_kind[RankCutoff])
+        for kind in (TextLoader, PairScorer):
+            assert len(by_kind[kind]) == len(DEPTHS)
+            assert len({id(n.cache) for n in by_kind[kind]}) == 1
+            assert by_kind[kind][0].cache is not None
+        cold, _ = plan.run(QUERIES)
+        warm, stats = plan.run(QUERIES)
+    _assert_bitwise(cold, want)
+    _assert_bitwise(warm, want)
+    assert stats.cache_misses == 0
+    assert len(scorer.pairs) == len(QUERIES) * max(DEPTHS)
+
+
+def test_building_a_plan_opens_only_its_own_stores(tmp_path, monkeypatch):
+    """Planning with a ``cache_dir`` opens one store per planned cache,
+    each under that directory, and writes nowhere else: no throwaway
+    store is opened to time the backend."""
+    import os
+    import tempfile
+    from _depth_grid import PairScorer, grid
+    from repro.caching import backends, kv, retriever
+    opened = []
+
+    def open_backend(spec, path, *a, **kw):
+        opened.append(os.path.abspath(path))
+        return real(spec, path, *a, **kw)
+    real = backends.open_backend
+    for mod in (backends, kv, retriever):
+        monkeypatch.setattr(mod, "open_backend", open_backend)
+    made = []
+    real_mkdtemp = tempfile.mkdtemp
+    monkeypatch.setattr(tempfile, "mkdtemp",
+                        lambda *a, **kw: made.append(a or kw)
+                        or real_mkdtemp(*a, **kw))
+    root = tmp_path / "cache"
+    with ExecutionPlan(grid(PairScorer()), cache_dir=str(root)) as plan:
+        dirs = {n["dir"] for n in plan.to_record()["nodes"] if n["dir"]}
+        assert len(dirs) == 3          # retriever, loader, scorer
+        assert set(os.listdir(root)) == dirs | {"plans"}
+    assert sorted(opened) == sorted(str(root / d) for d in dirs)
+    assert made == []

@@ -370,19 +370,16 @@ def test_optimize_rejects_unknown_passes():
         ExecutionPlan([a], optimize="fastest")
     with pytest.raises(ValueError, match="unknown optimizer pass"):
         ExecutionPlan([a], optimize=["cse", "bogus"])
-    assert set(OPTIMIZER_PASSES) == {"normalize", "cse", "pushdown",
-                                     "operand-order", "cache-place",
-                                     "cache-prune", "autotune"}
+    assert OPTIMIZER_PASSES == ("normalize", "cse", "pushdown",
+                                "cache-prune")
 
 
 def test_plan_stats_carry_optimizer_accounting():
     a = make_retriever("A")
     b = make_retriever("B", base=8.0)
     _, stats = ExecutionPlan([a + b, b + a, a % 3]).run(QUERIES)
-    assert stats.optimizer_passes == ["normalize", "cse", "pushdown",
-                                      "operand-order"]
-    assert set(stats.pass_times_s) == {"normalize", "cse", "pushdown",
-                                       "operand-order"}
+    assert stats.optimizer_passes == ["normalize", "cse", "pushdown"]
+    assert set(stats.pass_times_s) == {"normalize", "cse", "pushdown"}
     assert all(t >= 0 for t in stats.pass_times_s.values())
     assert stats.nodes_eliminated > 0
     assert "eliminated=" in str(stats)
@@ -397,7 +394,7 @@ def test_explain_lists_every_node_and_pass():
     b = make_retriever("B", base=8.0)
     plan = ExecutionPlan([a + b, b + a])
     text = plan.explain()
-    assert "passes=['normalize', 'cse', 'pushdown', 'operand-order']" in text
+    assert "passes=['normalize', 'cse', 'pushdown']" in text
     assert "shared, see above" in text   # the merged combine
     for node in plan.graph.nodes:
         if node.kind != "source":
@@ -579,8 +576,7 @@ def test_cse_reruns_after_pushdown_merges_fused_twins():
     _, stats = plan.run(QUERIES)
     assert stats.nodes_executed == 2
     assert stats.optimizer_passes == ["normalize", "cse", "pushdown",
-                                      "normalize", "cse", "operand-order"]
-    assert set(stats.pass_times_s) == {"normalize", "cse", "pushdown",
-                                       "operand-order"}
+                                      "normalize", "cse"]
+    assert set(stats.pass_times_s) == {"normalize", "cse", "pushdown"}
     run_both([CutRetriever("R", n=8) % 3 >> boost("pb2"),
               CutRetriever("R", n=3) >> boost("pb2")])

@@ -401,3 +401,21 @@ def test_streaming_executor_propagates_errors():
     out = svc2.submit("q1", "a query").result(60)
     assert out["qid"].tolist() == ["q1"]
     svc2.close()
+
+
+@pytest.mark.parametrize("where,knob", [
+    ("config", "max_batch"), ("config", "max_wait_ms"),
+    ("cli", "--max-batch"), ("cli", "--max-wait-ms")])
+def test_micro_batch_knobs_are_numbers(where, knob):
+    """The micro-batch knobs take numbers only: ``"auto"`` is refused by
+    ``ServeConfig`` and by the ``repro serve`` parser."""
+    if where == "config":
+        from repro.serve import ServeConfig
+        with pytest.raises(ValueError, match=f"{knob} must be a number"):
+            ServeConfig(**{knob: "auto"})
+    else:
+        from repro.cli import build_parser
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(["serve", knob, "auto"])
+        args = build_parser().parse_args(["serve", knob, "8"])
+        assert getattr(args, knob[2:].replace("-", "_")) == 8
